@@ -25,7 +25,6 @@ import numpy as np
 from benchmarks.common import (csv_row, parse_csv_rows, scaled_configs,
                                time_fn, time_fns_interleaved,
                                time_percentiles)
-from repro import compat
 from repro.configs.dlrm import DLRM_CONFIGS
 from repro.core import dlrm, hybrid
 from repro.core import embedding_source as es
@@ -521,10 +520,10 @@ def bench_source_dispatch(batch_size: int = 32, cache_k: int = 2048
             # gather fused INSIDE shard_map: each shard reduces the rows
             # it owns straight out of the dense id matrix, one psum of
             # reduced (n_bags, D) vectors
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 lambda aa, dd: se.dense_partial_reduce(aa, dd, "model"),
                 mesh=mesh, in_specs=(P("model", None), P(None, None)),
-                out_specs=P(None, None))
+                out_specs=P(None, None), check_vma=False)
             cold = fn(a, cold_ids).astype(a.dtype).astype(jnp.float32)
             return (hot + cold).reshape(b, t, d).astype(a.dtype)
 

@@ -70,6 +70,7 @@ from repro.configs.dlrm import DLRM_CONFIGS, DLRM_SMOKE
 from repro.core import dlrm
 from repro.core import sparse_engine as se
 from repro.data import DLRMSynthetic
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import RecEngine, requests_from_ragged_batch
 
 
@@ -525,6 +526,7 @@ def main() -> None:
                         help="fault-schedule seed for --chaos (6 = the "
                              "bench plan, guaranteed to drop AND reorder)")
     args = parser.parse_args()
+    use_compile_cache()
     if args.fleet:
         serve_fleet(args)
     elif args.het:

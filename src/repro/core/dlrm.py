@@ -15,7 +15,6 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import DLRMConfig
 from repro.core import dense_engine as de
 from repro.core import embedding_source as es
@@ -114,6 +113,16 @@ def project_tables(proj, emb: jax.Array) -> jax.Array:
     return jnp.stack(cols, axis=1)
 
 
+def _served_head(params: Dict, dense: jax.Array, emb: jax.Array,
+                 mesh, source) -> jax.Array:
+    """``head_logits`` on each device of the serving mesh (the sharded
+    source's, when no mesh is passed): the head's kernels run per device
+    on the replicated batch, and only the MLP weights enter."""
+    mesh = mesh if mesh is not None else getattr(source, "mesh", None)
+    mlp = {k: params[k] for k in ("bottom", "top")}
+    return es.per_device(head_logits, mesh)(mlp, dense, emb)
+
+
 def head_logits(mlp_params: Dict, dense: jax.Array,
                 emb: jax.Array) -> jax.Array:
     """The DLRM head shared by every forward AND training path: reduced
@@ -183,7 +192,7 @@ def forward(params: Dict, cfg: DLRMConfig, dense: jax.Array,
         emb = es.lookup_fixed(source, spec, indices)  # sparse stage
         if cfg.heterogeneous:
             emb = project_tables(params["proj"], emb)
-    return head_logits(params, dense, emb)            # dense stage
+    return _served_head(params, dense, emb, mesh, source)   # dense stage
 
 
 def forward_ragged(params: Dict, cfg: DLRMConfig, dense: jax.Array,
@@ -244,7 +253,7 @@ def forward_ragged(params: Dict, cfg: DLRMConfig, dense: jax.Array,
                                  max_l=max_l)
         if cfg.heterogeneous:
             emb = project_tables(params["proj"], emb)
-    return head_logits(params, dense, emb)
+    return _served_head(params, dense, emb, mesh, source)
 
 
 def _bce(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -465,10 +474,11 @@ def _make_train_step_ragged_sharded(cfg: DLRMConfig, spec: se.ArenaSpec, *,
         return new_shard, new_arena_state, new_mlp, new_mlp_state, loss, \
             rows
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(axis, None), arena_state_spec, P(), P(), P()),
         out_specs=(P(axis, None), arena_state_spec, P(), P(), P(), P()),
+        check_vma=False,
     )
 
     def step(params, opt_state, batch):

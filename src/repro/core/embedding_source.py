@@ -64,7 +64,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import sparse_engine as se
 from repro.kernels import ops
 from repro.obs.tracing import stage as obs_stage
@@ -185,6 +184,18 @@ class EmbeddingSource:
         raise NotImplementedError(
             f"{type(self).__name__} cannot be row-sharded; wrap a leaf "
             f"source (FpArena / QuantizedArena) in ShardedArena instead")
+
+
+def per_device(fn, mesh: Optional[jax.sharding.Mesh]):
+    """``fn`` run whole on each device of ``mesh``, over operands that are
+    replicated on it. Pallas TPU kernels cannot be partitioned
+    automatically, so code that calls them on a multi-device mesh outside
+    a ``ShardedArena`` runs under this shard_map. Identity otherwise."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 @register_source(("arena",))
@@ -315,11 +326,11 @@ class ShardedArena(EmbeddingSource):
             return local_fn(jax.tree_util.tree_unflatten(treedef, ls),
                             *rest)
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=tuple(P(self.axis, None) for _ in leaves)
             + tuple(batch_specs),
-            out_specs=out_spec)
+            out_specs=out_spec, check_vma=False)
         return fn(*leaves, *batch_args)
 
     def _data_axes(self):
@@ -441,8 +452,9 @@ class CachedSource(EmbeddingSource):
             return rows.sum(axis=1)
         # sharded (or any other) cold source: fused hot pass + the cold
         # source's own fused pass over the redirected ids
-        hot = ops.fused_segment_sum(self.hot.hot_rows, slots,
-                                    null_row=self.k)
+        hot = per_device(
+            lambda h, i: ops.fused_segment_sum(h, i, null_row=self.k),
+            getattr(cold, "mesh", None))(self.hot.hot_rows, slots)
         return hot + cold.reduce_dense(spec, cold_ids)
 
 

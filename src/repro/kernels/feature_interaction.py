@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels.gemm import f32_precision
 
 
 def _interact_kernel(x_ref, o_ref):
@@ -23,6 +23,7 @@ def _interact_kernel(x_ref, o_ref):
         x, x,
         dimension_numbers=(((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
+        precision=f32_precision(x.dtype),
     ).astype(o_ref.dtype)
 
 
@@ -39,7 +40,7 @@ def interaction(x: jax.Array, *, bb: int = 64,
         in_specs=[pl.BlockSpec((bb, f, d), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((bb, f, f), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, f, f), x.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

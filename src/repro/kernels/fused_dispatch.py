@@ -37,10 +37,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels.embedding_gather import Rows, arbitrary, chunks
 
 
-def _fused_kernel(ids_ref, table_ref, o_ref, acc_ref, *, max_l: int):
+def _fused_kernel(ids_ref, table_ref, o_ref, acc_ref, *, max_l: int,
+                  tab: Rows, out: Rows):
+    b = pl.program_id(0)
     l = pl.program_id(2)
 
     @pl.when(l == 0)
@@ -50,11 +52,11 @@ def _fused_kernel(ids_ref, table_ref, o_ref, acc_ref, *, max_l: int):
     # One gathered row per grid step, row chosen by the prefetched dense
     # id; fill slots point at the always-zero null row, so the reduction
     # needs no validity mask at all.
-    acc_ref[...] += table_ref[...].astype(jnp.float32)
+    acc_ref[...] += tab.read(table_ref, ids_ref[b * max_l + l])
 
     @pl.when(l == max_l - 1)
     def _flush():
-        o_ref[...] = acc_ref[...]
+        out.write(o_ref, b, acc_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -70,30 +72,34 @@ def fused_segment_sum(table: jax.Array, dense_ids: jax.Array, *,
     b, max_l = dense_ids.shape
     if max_l == 0:
         return jnp.zeros((b, d), jnp.float32)
-    grid = (b, 1, max_l)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, d), lambda bb, dd, ll, ids: (ids[bb, ll], dd)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda bb, dd, ll, ids: (bb, dd)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-    )
-    fn = pl.pallas_call(
-        functools.partial(_fused_kernel, max_l=max_l),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )
-    return fn(dense_ids, table)
+    tab = Rows.of(v, d)
+    parts = []
+    for s, e in chunks(b, max_l):
+        out = Rows.of(e - s, d)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(e - s, 1, max_l),
+            in_specs=[
+                tab.spec(lambda bb, dd, ll, ids: (ids[bb * max_l + ll], dd)),
+            ],
+            out_specs=out.spec(lambda bb, dd, ll, ids: (bb, dd)),
+            scratch_shapes=[tab.scratch()],
+        )
+        fn = pl.pallas_call(
+            functools.partial(_fused_kernel, max_l=max_l, tab=tab, out=out),
+            grid_spec=grid_spec,
+            out_shape=out.shape(e - s, d, jnp.float32),
+            compiler_params=arbitrary(3),
+            interpret=interpret,
+        )
+        parts.append(out.view(fn(dense_ids[s:e].reshape(-1),
+                                 tab.view(table))))
+    return jnp.concatenate(parts)
 
 
 def _cached_kernel(slots_ref, cold_ref, hot_ref, arena_ref, o_ref, acc_ref,
-                   *, max_l: int):
+                   *, max_l: int, hot: Rows, tab: Rows, out: Rows):
+    b = pl.program_id(0)
     l = pl.program_id(2)
 
     @pl.when(l == 0)
@@ -104,12 +110,13 @@ def _cached_kernel(slots_ref, cold_ref, hot_ref, arena_ref, o_ref, acc_ref,
     # rows is nonzero (a miss reads the hot arena's zero null slot, a hit
     # reads the cold arena's zero null row), so accumulating their sum is
     # bit-for-bit the uncached reduction — in ONE pass.
-    acc_ref[...] += hot_ref[...].astype(jnp.float32) \
-        + arena_ref[...].astype(jnp.float32)
+    i = b * max_l + l
+    acc_ref[...] += hot.read(hot_ref, slots_ref[i]) \
+        + tab.read(arena_ref, cold_ref[i])
 
     @pl.when(l == max_l - 1)
     def _flush():
-        o_ref[...] = acc_ref[...]
+        out.write(o_ref, b, acc_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -124,59 +131,67 @@ def fused_cached_segment_sum(hot_rows: jax.Array, arena: jax.Array,
     equal to ``fused_segment_sum(hot_rows, slots) +
     fused_segment_sum(arena, cold_ids)`` computed in a single walk.
     """
-    d = arena.shape[1]
+    v, d = arena.shape
     b, max_l = slots.shape
     assert cold_ids.shape == slots.shape, (cold_ids.shape, slots.shape)
     assert hot_rows.shape[1] == d, (hot_rows.shape, arena.shape)
     if max_l == 0:
         return jnp.zeros((b, d), jnp.float32)
-    grid = (b, 1, max_l)
+    hot, tab = Rows.of(hot_rows.shape[0], d), Rows.of(v, d)
+    parts = []
+    for s, e in chunks(b, 2 * max_l):
+        out = Rows.of(e - s, d)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(e - s, 1, max_l),
+            in_specs=[
+                hot.spec(lambda bb, dd, ll, sl, co: (sl[bb * max_l + ll],
+                                                     dd)),
+                tab.spec(lambda bb, dd, ll, sl, co: (co[bb * max_l + ll],
+                                                     dd)),
+            ],
+            out_specs=out.spec(lambda bb, dd, ll, sl, co: (bb, dd)),
+            scratch_shapes=[tab.scratch()],
+        )
+        fn = pl.pallas_call(
+            functools.partial(_cached_kernel, max_l=max_l, hot=hot, tab=tab,
+                              out=out),
+            grid_spec=grid_spec,
+            out_shape=out.shape(e - s, d, jnp.float32),
+            compiler_params=arbitrary(3),
+            interpret=interpret,
+        )
+        parts.append(out.view(fn(slots[s:e].reshape(-1),
+                                 cold_ids[s:e].reshape(-1),
+                                 hot.view(hot_rows), tab.view(arena))))
+    return jnp.concatenate(parts)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, d),
-                         lambda bb, dd, ll, sl, co: (sl[bb, ll], dd)),
-            pl.BlockSpec((1, d),
-                         lambda bb, dd, ll, sl, co: (co[bb, ll], dd)),
-        ],
-        out_specs=pl.BlockSpec((1, d),
-                               lambda bb, dd, ll, sl, co: (bb, dd)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-    )
-    fn = pl.pallas_call(
-        functools.partial(_cached_kernel, max_l=max_l),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )
-    return fn(slots, cold_ids, hot_rows, arena)
 
-
-def _int4_kernel(ids_ref, packed_ref, scales_ref, o_ref, acc_ref, *,
-                 max_l: int, dim: int):
+def _int4_kernel(ids_ref, packed_ref, scales_ref, lo_ref, hi_ref, lo_acc,
+                 hi_acc, *, max_l: int, tab: Rows, sc: Rows, out: Rows):
+    b = pl.program_id(0)
     l = pl.program_id(2)
 
     @pl.when(l == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        lo_acc[...] = jnp.zeros_like(lo_acc)
+        hi_acc[...] = jnp.zeros_like(hi_acc)
 
     # Unpack the gathered row's nibbles in-register: biased codes (q+8,
-    # 8 == zero) interleaved two per byte. A null row's scale is zero, so
-    # fill slots contribute nothing — same masking-free walk as the fp
-    # kernel, at an eighth of the gather bytes.
-    p = packed_ref[...].astype(jnp.int32)        # (1, P)
-    lo = (p & 0xF) - 8
-    hi = (p >> 4) - 8
-    codes = jnp.stack([lo, hi], axis=-1).reshape(1, 2 * p.shape[-1])
-    acc_ref[...] += codes[:, :dim].astype(jnp.float32) * scales_ref[0, 0]
+    # 8 == zero), byte k holding dims 2k (low) and 2k+1 (high). A null
+    # row's scale is zero, so fill slots contribute nothing — same
+    # masking-free walk as the fp kernel, at an eighth of the gather bytes.
+    # Even and odd dims accumulate apart and are interleaved by the caller.
+    row = ids_ref[b * max_l + l]
+    p = tab.read(packed_ref, row).astype(jnp.int32)
+    scale = sc.read(scales_ref, row)
+    lo_acc[...] += ((p & 0xF) - 8).astype(jnp.float32) * scale
+    hi_acc[...] += ((p >> 4) - 8).astype(jnp.float32) * scale
 
     @pl.when(l == max_l - 1)
     def _flush():
-        o_ref[...] = acc_ref[...]
+        out.write(lo_ref, b, lo_acc[...])
+        out.write(hi_ref, b, hi_acc[...])
 
 
 @functools.partial(jax.jit, static_argnames=("dim", "interpret"))
@@ -196,24 +211,30 @@ def fused_int4_segment_sum(packed: jax.Array, scales: jax.Array,
     b, max_l = dense_ids.shape
     if max_l == 0:
         return jnp.zeros((b, dim), jnp.float32)
-    grid = (b, 1, max_l)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, p), lambda bb, dd, ll, ids: (ids[bb, ll], dd)),
-            pl.BlockSpec((1, 1), lambda bb, dd, ll, ids: (ids[bb, ll], dd)),
-        ],
-        out_specs=pl.BlockSpec((1, dim), lambda bb, dd, ll, ids: (bb, dd)),
-        scratch_shapes=[pltpu.VMEM((1, dim), jnp.float32)],
-    )
-    fn = pl.pallas_call(
-        functools.partial(_int4_kernel, max_l=max_l, dim=dim),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, dim), jnp.float32),
-        compiler_params=compat.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )
-    return fn(dense_ids, packed, scales)
+    tab, sc = Rows.of(v, p), Rows.of(v, 1)
+    parts = []
+    for s, e in chunks(b, max_l):
+        out = Rows.of(e - s, p)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(e - s, 1, max_l),
+            in_specs=[
+                tab.spec(lambda bb, dd, ll, ids: (ids[bb * max_l + ll], dd)),
+                sc.spec(lambda bb, dd, ll, ids: (ids[bb * max_l + ll], dd)),
+            ],
+            out_specs=[out.spec(lambda bb, dd, ll, ids: (bb, dd))] * 2,
+            scratch_shapes=[tab.scratch(), tab.scratch()],
+        )
+        fn = pl.pallas_call(
+            functools.partial(_int4_kernel, max_l=max_l, tab=tab, sc=sc,
+                              out=out),
+            grid_spec=grid_spec,
+            out_shape=[out.shape(e - s, p, jnp.float32)] * 2,
+            compiler_params=arbitrary(3),
+            interpret=interpret,
+        )
+        lo, hi = fn(dense_ids[s:e].reshape(-1), tab.view(packed),
+                    sc.view(scales))
+        codes = jnp.stack([out.view(lo), out.view(hi)], axis=-1)
+        parts.append(codes.reshape(e - s, 2 * p)[:, :dim])
+    return jnp.concatenate(parts)

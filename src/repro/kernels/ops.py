@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: on TPU the compiled Pallas kernels run; on CPU (this
-container) the mathematically identical XLA path from ``ref.py`` runs so the
+Dispatch policy: on TPU the compiled Pallas kernels run; on any other
+backend the mathematically identical XLA path from ``ref.py`` runs so the
 framework is usable end-to-end, and tests exercise the kernel bodies with
 ``interpret=True``. The active implementation can be forced globally:
 

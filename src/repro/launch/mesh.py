@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-from repro import compat
 import jax
 
 
@@ -26,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"{len(devices)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             f"for the dry-run")
-    return compat.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
@@ -36,4 +35,6 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str],
     for s in shape:
         n *= s
     devices = (devices or jax.devices())[:n]
-    return compat.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axes))

@@ -13,7 +13,6 @@ with --resume, straggler monitor logging.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import jax
@@ -27,6 +26,7 @@ from repro.configs.registry import ARCHS, SMOKE_ARCHS
 from repro.core import dlrm as dlrm_mod
 from repro.data import DLRMSynthetic, LMSynthetic
 from repro.distributed.fault_tolerance import StragglerMonitor
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import api
 
@@ -194,6 +194,10 @@ def _mesh(args):
                 "--shards builds its own N-way 'model' mesh and cannot be "
                 "combined with --mesh pod/multipod (the production meshes "
                 "fix their own model-axis width); pass one or the other")
+        if len(jax.devices()) < args.shards:
+            raise SystemExit(
+                f"--shards {args.shards} needs {args.shards} devices; this "
+                f"host has {len(jax.devices())}")
         from repro.launch.mesh import make_mesh
         return make_mesh((args.shards,), ("model",))
     if args.mesh == "none":
@@ -244,16 +248,7 @@ def main() -> None:
                    help="with --ragged: collect host spans and enable "
                         "jax.profiler stage annotations in jitted code")
     args = p.parse_args()
-
-    if args.shards > 1:
-        # must land before the first backend touch; on CPU this simulates
-        # the N chips the mesh needs (real TPU fleets already have them)
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{args.shards}").strip()
-
+    use_compile_cache()
     if args.arch.startswith("dlrm"):
         train_dlrm(args)
     else:

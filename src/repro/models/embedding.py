@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.distributed.sharding import active_mesh, batch_axes, constrain
 from repro.models.params import Builder
 
@@ -56,11 +55,11 @@ def embed_tokens(table: jax.Array, tokens: jax.Array) -> jax.Array:
         n_batch_shards = int(np.prod([mesh.shape[a] for a in ba])) if ba else 1
         if tokens.shape[0] % n_batch_shards == 0:
             bspec = ba if len(ba) > 1 else (ba[0] if ba else None)
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 lambda t, tok: _local_gather(t, tok, "model"),
                 mesh=mesh,
                 in_specs=(P("model", None), P(bspec, None)),
-                out_specs=P(bspec, None, None))
+                out_specs=P(bspec, None, None), check_vma=False)
             return fn(table, tokens)
     # Fallback (no mesh / tiny batch): direct gather; GSPMD partitions it.
     return jnp.take(table, tokens, axis=0)

@@ -50,10 +50,14 @@ from repro.kernels import ops
 
 __all__ = ["HostStore", "HostTier"]
 
-# issue host->device copies eagerly (device_put futures) only when a real
-# accelerator is attached; the CPU backend's jit argument conversion is
-# the same copy without the extra Python hop
-_EXPLICIT_PUT = jax.default_backend() != "cpu"
+
+@functools.cache
+def _explicit_put() -> bool:
+    """Issue host->device copies eagerly (device_put futures) only when a
+    real accelerator is attached; the CPU backend's jit argument conversion
+    is the same copy without the extra Python hop. Decided at first use, so
+    importing this module never starts a backend."""
+    return jax.default_backend() != "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -444,7 +448,7 @@ class HostStore:
             # CPU backend the jit argument conversion IS that (zero-copy)
             # transfer, and an explicit device_put would only add a
             # Python round-trip to the same buffer.
-            rows_dev = jax.device_put(rows_np) if _EXPLICIT_PUT \
+            rows_dev = jax.device_put(rows_np) if _explicit_put() \
                 else rows_np
             self.staging, self.slot_of = _apply_stage(
                 self.staging, self.slot_of, rows_dev,

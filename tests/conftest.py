@@ -8,119 +8,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-# ---------------------------------------------------------------------------
-# Hypothesis guard: tier-1 must collect and run everywhere, including
-# containers without `hypothesis` installed (pip install is unavailable).
-# When the real package is absent we register a minimal deterministic
-# stand-in under the same module names: `@given` runs the test body over a
-# small fixed grid of boundary examples per strategy instead of random
-# search. Property tests therefore still *execute* (weaker search, same
-# oracle) rather than erroring at collection or silently skipping. With
-# `pip install -r requirements-dev.txt` (e.g. in CI) the real hypothesis
-# takes over untouched.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import functools
-    import inspect
-    import itertools
-    import types
-
-    _MAX_COMBOS = 16
-
-    class _Strategy:
-        """A strategy reduced to an explicit list of boundary examples."""
-
-        def __init__(self, examples):
-            self.examples = list(examples)
-
-        def map(self, fn):
-            return _Strategy([fn(e) for e in self.examples])
-
-        def filter(self, pred):
-            kept = [e for e in self.examples if pred(e)]
-            return _Strategy(kept or self.examples[:1])
-
-    def _integers(min_value, max_value):
-        span = max_value - min_value
-        vals = {min_value, max_value,
-                min_value + span // 2, min_value + span // 3}
-        return _Strategy(sorted(vals))
-
-    def _sampled_from(seq):
-        return _Strategy(list(seq))
-
-    def _booleans():
-        return _Strategy([False, True])
-
-    def _lists(elem, min_size=0, max_size=None):
-        if max_size is None:
-            max_size = min_size + 3
-        ex = elem.examples
-        out = []
-        for size in {min_size, max_size, (min_size + max_size) // 2}:
-            out.append([ex[i % len(ex)] for i in range(size)])
-            out.append([ex[(i + 1) % len(ex)] for i in range(size)])
-        return _Strategy(out)
-
-    def _tuples(*strats):
-        return _Strategy(list(itertools.islice(
-            itertools.product(*[s.examples for s in strats]), _MAX_COMBOS)))
-
-    def _just(v):
-        return _Strategy([v])
-
-    def _given(*strats, **kw_strats):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                pools = [s.examples for s in strats]
-                kw_names = list(kw_strats)
-                pools += [kw_strats[k].examples for k in kw_names]
-                combos = itertools.islice(itertools.product(*pools),
-                                          _MAX_COMBOS)
-                for combo in combos:
-                    pos = combo[:len(strats)]
-                    kws = dict(zip(kw_names, combo[len(strats):]))
-                    fn(*args, *pos, **kws, **kwargs)
-
-            # Hide the strategy-filled parameters from pytest's fixture
-            # resolution (real hypothesis does the same): positional
-            # strategies bind to the *rightmost* parameters, keyword
-            # strategies to their names; anything left is a fixture.
-            sig = inspect.signature(fn)
-            params = list(sig.parameters.values())
-            keep = params[:len(params) - len(strats)] if strats else params
-            keep = [p for p in keep if p.name not in kw_strats]
-            wrapper.__signature__ = sig.replace(parameters=keep)
-            return wrapper
-        return deco
-
-    def _settings(*a, **kw):
-        def deco(fn):
-            return fn
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = lambda cond: None
-    _hyp.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
-    _hyp.__is_repro_fallback__ = True
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.sampled_from = _sampled_from
-    _st.booleans = _booleans
-    _st.lists = _lists
-    _st.tuples = _tuples
-    _st.just = _just
-
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
 
 @pytest.fixture
 def rng():

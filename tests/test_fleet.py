@@ -17,9 +17,8 @@ the protocol invariants the fleet design claims:
       actually published, monotonically.
 
 Every scenario replays from its seed: no wall-clock randomness anywhere
-in the chaos path. Under the real ``hypothesis`` package the pinned
-``fleet`` profile (derandomized) keeps CI schedules reproducible; under
-the conftest fallback the fixed example grid is deterministic already.
+in the chaos path: the pinned ``fleet`` hypothesis profile
+(derandomized) keeps CI schedules reproducible.
 """
 import os
 import sys
@@ -31,7 +30,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import hypothesis
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -51,13 +49,10 @@ from repro.training.online import (OnlineCacheConfig, OnlineTrainer,  # noqa: E4
                                    make_drifting_zipf)
 
 # Pinned hypothesis profile: CI runs HYPOTHESIS_PROFILE=fleet so chaos
-# schedules are derandomized (replayable run to run). The conftest
-# fallback stub has no profile machinery — and needs none, its example
-# grid is already fixed.
-if not getattr(hypothesis, "__is_repro_fallback__", False):
-    settings.register_profile("fleet", deadline=None, max_examples=4,
-                              derandomize=True, print_blob=True)
-    settings.load_profile(os.environ.get("HYPOTHESIS_PROFILE", "fleet"))
+# schedules are derandomized (replayable run to run).
+settings.register_profile("fleet", deadline=None, max_examples=4,
+                          derandomize=True, print_blob=True)
+settings.load_profile(os.environ.get("HYPOTHESIS_PROFILE", "fleet"))
 
 # The fault mixes the properties quantify over: lossy, duplicating, and
 # heavily delaying (delay is what manufactures genuine reordering).

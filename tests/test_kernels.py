@@ -18,13 +18,24 @@ def _close(a, b, tol=2e-2):
     np.testing.assert_allclose(a, b, rtol=tol, atol=tol)
 
 
+def _row_call(kernel, *args, split=False, **kw):
+    """A row-streaming kernel in interpret mode. ``split`` shrinks the
+    SMEM id budget so the batch is walked in many calls (outside jit, so
+    no cached one-call trace is reused)."""
+    if not split:
+        return kernel(*args, interpret=True, **kw)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(embedding_gather, "SMEM_WORDS", 12)
+        return kernel.__wrapped__(*args, interpret=True, **kw)
+
+
 # ---------------------------------------------------------------------------
 # GEMM (dense engine)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("m,k,n", [(8, 16, 8), (128, 128, 128),
                                    (130, 70, 150), (256, 33, 64),
-                                   (1, 512, 1)])
+                                   (1, 512, 1), (16, 131, 32)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gemm_matches_oracle(rng, m, k, n, dtype):
     x = jnp.asarray(rng.randn(m, k), dtype)
@@ -47,13 +58,17 @@ def test_gemm_block_shapes(rng, bm, bn, bk):
 # Embedding gather-reduce (sparse engine)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("v,d,b,l", [(100, 32, 4, 1), (1000, 32, 16, 20),
-                                     (512, 128, 8, 80), (64, 48, 3, 5)])
+@pytest.mark.parametrize("v,d,b,l,split", [(100, 32, 4, 1, False),
+                                           (1000, 32, 16, 20, False),
+                                           (512, 128, 8, 80, False),
+                                           (64, 48, 3, 5, False),
+                                           (1000, 32, 16, 20, True),
+                                           (300, 256, 5, 3, True)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_embedding_bag_matches_oracle(rng, v, d, b, l, dtype):
+def test_embedding_bag_matches_oracle(rng, v, d, b, l, split, dtype):
     table = jnp.asarray(rng.randn(v, d), dtype)
     idx = jnp.asarray(rng.randint(0, v, (b, l)), jnp.int32)
-    got = embedding_gather.embedding_bag(table, idx, interpret=True)
+    got = _row_call(embedding_gather.embedding_bag, table, idx, split=split)
     want = ref.embedding_bag(table, idx)
     _close(got, want, 1e-5 if dtype == jnp.float32 else 5e-2)
 
@@ -91,17 +106,40 @@ def test_embedding_bag_property(v, b, l, seed):
     _close(out3, 2.0 * np.asarray(out1), 1e-4)
 
 
-def test_sparse_lengths_sum_ragged(rng):
-    """Paper Fig. 2 semantics with ragged offsets."""
+@pytest.mark.parametrize("impl", ["ref", "kernel", "kernel_split"])
+def test_sparse_lengths_sum_ragged(rng, impl):
+    """Paper Fig. 2 semantics with ragged offsets (padded stream), for the
+    oracle and the ragged kernel in one call or many."""
     table = jnp.asarray(rng.randn(50, 8), jnp.float32)
-    indices = jnp.asarray(rng.randint(0, 50, (10,)), jnp.int32)
+    indices = jnp.asarray(rng.randint(0, 50, (12,)), jnp.int32)
     offsets = jnp.asarray([0, 3, 3, 7, 10], jnp.int32)
-    out = ref.sparse_lengths_sum(table, indices, offsets)
+    if impl == "ref":
+        out = ref.sparse_lengths_sum(table, indices, offsets)
+    else:
+        out = _row_call(embedding_gather.sparse_lengths_sum, table, indices,
+                        offsets, max_l=4, split=impl == "kernel_split")
     for b in range(4):
         lo, hi = int(offsets[b]), int(offsets[b + 1])
         want = np.asarray(table)[np.asarray(indices[lo:hi])].sum(0) \
             if hi > lo else np.zeros(8)
         _close(out[b], want, 1e-5)
+
+
+@pytest.mark.parametrize("v,d,b,l,split", [(19, 8, 5, 4, False),
+                                           (19, 8, 5, 4, True),
+                                           (300, 32, 40, 6, True),
+                                           (40, 128, 6, 3, True)])
+def test_sls_grad_table_matches_oracle(rng, v, d, b, l, split):
+    """The scatter-add backward, including runs of one destination row
+    that a split walk cuts between calls (few rows, many positions)."""
+    n = b * l
+    idx = jnp.asarray(rng.randint(0, v, (n + 3,)), jnp.int32)
+    off = jnp.asarray(np.sort(rng.randint(0, n + 1, b + 1)), jnp.int32)
+    off = off.at[0].set(0)
+    g = jnp.asarray(rng.randn(b, d), jnp.float32)
+    got = _row_call(embedding_gather.sls_grad_table, g, idx, off, n_rows=v,
+                    split=split)
+    _close(got, ref.sls_grad_table(g, idx, off, v), 1e-5)
 
 
 def test_embedding_bag_grad_is_scatter_add(rng):
@@ -220,29 +258,50 @@ def _dense_case(rng, v, b, l, null=None):
     return jnp.asarray(ids, jnp.int32)
 
 
-@pytest.mark.parametrize("v,d,b,l", [(100, 32, 4, 1), (257, 16, 8, 6),
-                                     (64, 128, 3, 9), (1, 1, 2, 3),
-                                     (50, 1, 5, 4), (1, 48, 4, 2)])
+@pytest.mark.parametrize("v,d,b,l,split", [(100, 32, 4, 1, False),
+                                           (257, 16, 8, 6, False),
+                                           (64, 128, 3, 9, False),
+                                           (1, 1, 2, 3, False),
+                                           (50, 1, 5, 4, False),
+                                           (1, 48, 4, 2, False),
+                                           (257, 16, 8, 6, True),
+                                           (300, 160, 7, 3, True)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_segment_sum_matches_oracle(rng, v, d, b, l, dtype):
+def test_fused_segment_sum_matches_oracle(rng, v, d, b, l, split, dtype):
     table = jnp.asarray(rng.randn(v, d), dtype)
     ids = _dense_case(rng, v, b, l, null=v - 1)
-    got = fused_dispatch.fused_segment_sum(table, ids, interpret=True)
+    got = _row_call(fused_dispatch.fused_segment_sum, table, ids,
+                    split=split)
     want = ref.fused_segment_sum(table, ids)
     _close(got, want, 1e-5 if dtype == jnp.float32 else 5e-2)
 
 
-@pytest.mark.parametrize("v,k,d,b,l", [(120, 9, 8, 4, 5), (64, 1, 16, 3, 3),
-                                       (256, 33, 32, 6, 7)])
-def test_fused_cached_segment_sum_matches_oracle(rng, v, k, d, b, l):
+@pytest.mark.parametrize("v,k,d,b,l,split", [(120, 9, 8, 4, 5, False),
+                                             (64, 1, 16, 3, 3, False),
+                                             (256, 33, 32, 6, 7, False),
+                                             (256, 33, 32, 6, 7, True),
+                                             (200, 140, 128, 5, 2, True)])
+def test_fused_cached_segment_sum_matches_oracle(rng, v, k, d, b, l, split):
     arena = jnp.asarray(rng.randn(v, d), jnp.float32)
     hot = jnp.asarray(rng.randn(k + 1, d), jnp.float32)
     slots = _dense_case(rng, k + 1, b, l)
     cold = _dense_case(rng, v, b, l)
-    got = fused_dispatch.fused_cached_segment_sum(hot, arena, slots, cold,
-                                                  interpret=True)
+    got = _row_call(fused_dispatch.fused_cached_segment_sum, hot, arena,
+                    slots, cold, split=split)
     want = ref.fused_cached_segment_sum(hot, arena, slots, cold)
     _close(got, want, 1e-5)
+
+
+@pytest.mark.parametrize("v,dim,b,l,split", [(90, 32, 6, 5, False),
+                                             (90, 31, 6, 5, True),
+                                             (40, 300, 3, 2, True)])
+def test_fused_int4_segment_sum_matches_oracle(rng, v, dim, b, l, split):
+    packed, scales = ref.int4_pack(jnp.asarray(rng.randn(v, dim),
+                                               jnp.float32))
+    ids = _dense_case(rng, v, b, l)
+    got = _row_call(fused_dispatch.fused_int4_segment_sum, packed, scales,
+                    ids, dim=dim, split=split)
+    _close(got, ref.fused_int4_segment_sum(packed, scales, ids, dim), 1e-5)
 
 
 def test_fused_ops_pallas_equals_xla_lookup_and_grad(rng):

@@ -589,3 +589,22 @@ def test_plan_validation_rejects_conflicting_knobs():
         es.SourceSpec(layout="fixed", tiers=pol)
     with pytest.raises(AssertionError):
         storage.TierPolicy(hot=4, warm=4, cold="float8")
+
+
+def test_import_starts_no_backend():
+    """Importing the storage tiers (and with them the serving and training
+    packages) must not start a JAX backend: on a TPU host that would take
+    the chip in any process that merely imports them."""
+    import os
+    import subprocess
+    import sys
+    code = ("import repro.storage, repro.serving, repro.training\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = {"PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+           "PYTHONPATH": src,
+           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
