@@ -1,0 +1,2 @@
+"""Share of the window in which no op ran on the device."""
+from bench.readers import device_idle_pct as read  # noqa: F401
