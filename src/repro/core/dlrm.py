@@ -399,12 +399,15 @@ def make_train_step_ragged(cfg: DLRMConfig, *, max_l: int, lr: float = 1e-3,
             mlp_params, emb)
 
         d_bags = d_emb.reshape(n_bags, spec.dim)
-        rows, row_g = so.source_row_grads(spec, d_bags, batch["indices"],
-                                          batch["offsets"])
-        new_arena, arena_state = arena_opt.update(
-            params["arena"], opt_state["arena"], rows, row_g)
-        new_mlp, mlp_state = mlp_opt.update(d_mlp, opt_state["mlp"],
-                                            mlp_params)
+        with obs_stage("sparse_update"):
+            rows, row_g = so.source_row_grads(spec, d_bags,
+                                              batch["indices"],
+                                              batch["offsets"])
+            new_arena, arena_state = arena_opt.update(
+                params["arena"], opt_state["arena"], rows, row_g)
+        with obs_stage("dense_update"):
+            new_mlp, mlp_state = mlp_opt.update(d_mlp, opt_state["mlp"],
+                                                mlp_params)
         new_params = dict(new_mlp)
         new_params["arena"] = new_arena
         return new_params, {"arena": arena_state, "mlp": mlp_state}, \
@@ -463,14 +466,17 @@ def _make_train_step_ragged_sharded(cfg: DLRMConfig, spec: se.ArenaSpec, *,
             lambda g: jax.lax.pmean(g, axis), d_mlp)
 
         d_bags = d_emb.reshape(n_bags, spec.dim)
-        rows, row_g = so.ragged_row_grads(d_bags, flat, batch["offsets"],
-                                          fill_row=null)
-        lrows, lg = so.shard_local_rows(rows, row_g, lo=lo, vlocal=vlocal,
-                                        null_row=null)
-        new_shard, new_arena_state = arena_opt.update(
-            arena_shard, arena_state, lrows, lg)
-        new_mlp, new_mlp_state = mlp_opt.update(d_mlp, mlp_state,
-                                                mlp_params)
+        with obs_stage("sparse_update"):
+            rows, row_g = so.ragged_row_grads(d_bags, flat,
+                                              batch["offsets"],
+                                              fill_row=null)
+            lrows, lg = so.shard_local_rows(rows, row_g, lo=lo,
+                                            vlocal=vlocal, null_row=null)
+            new_shard, new_arena_state = arena_opt.update(
+                arena_shard, arena_state, lrows, lg)
+        with obs_stage("dense_update"):
+            new_mlp, new_mlp_state = mlp_opt.update(d_mlp, mlp_state,
+                                                    mlp_params)
         return new_shard, new_arena_state, new_mlp, new_mlp_state, loss, \
             rows
 
@@ -569,12 +575,15 @@ def _make_train_step_group(cfg: DLRMConfig, spec: se.ArenaSpec, *,
             head_params, emb)
 
         d_bags = d_emb.reshape(n_bags, spec.dim)
-        per_table = so.group_row_grads(specs, d_bags, batch["indices"],
-                                       batch["offsets"], max_l=max_l)
-        new_tables, tables_state = arena_opt.update(
-            params["tables"], opt_state["tables"], per_table)
-        new_head, mlp_state = mlp_opt.update(d_head, opt_state["mlp"],
-                                             head_params)
+        with obs_stage("sparse_update"):
+            per_table = so.group_row_grads(specs, d_bags,
+                                           batch["indices"],
+                                           batch["offsets"], max_l=max_l)
+            new_tables, tables_state = arena_opt.update(
+                params["tables"], opt_state["tables"], per_table)
+        with obs_stage("dense_update"):
+            new_head, mlp_state = mlp_opt.update(d_head, opt_state["mlp"],
+                                                 head_params)
         new_params = dict(new_head)
         new_params["tables"] = new_tables
         return new_params, {"tables": tables_state, "mlp": mlp_state}, \

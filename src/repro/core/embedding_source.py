@@ -137,11 +137,15 @@ class EmbeddingSource:
                     offsets: jax.Array, *, max_l: int) -> jax.Array:
         """(N,) per-table row ids + (n_bags+1,) offsets -> f32
         (n_bags, D). Default: flatten into the uniform arena layout,
-        relayout once, reduce fused."""
-        flat = se.flatten_ragged_indices(spec, indices, offsets)
-        dense = se.ragged_dense_ids(flat, offsets, max_l=max_l,
-                                    fill=spec.null_row)
-        return self.reduce_dense(spec, dense)
+        relayout once, reduce fused. The two halves carry the scopes
+        ``relayout`` and ``gather_reduce``, so a device trace splits the
+        lookup's time between them."""
+        with obs_stage("relayout"):
+            flat = se.flatten_ragged_indices(spec, indices, offsets)
+            dense = se.ragged_dense_ids(flat, offsets, max_l=max_l,
+                                        fill=spec.null_row)
+        with obs_stage("gather_reduce"):
+            return self.reduce_dense(spec, dense)
 
     def reduce_fixed_ids(self, spec: se.ArenaSpec,
                          indices: jax.Array) -> jax.Array:
@@ -543,23 +547,26 @@ class TableGroupSource(EmbeddingSource):
         # positions, not T*N (the old per-member full-stream walk). -1
         # marks short/padded slots so each table can redirect them to
         # its OWN always-zero null row below.
-        dense = se.ragged_dense_ids(indices, offsets, max_l=max_l,
-                                    fill=-1)
-        dense = dense.reshape(b, t_count, max_l)
+        with obs_stage("relayout"):
+            dense = se.ragged_dense_ids(indices, offsets, max_l=max_l,
+                                        fill=-1)
+            dense = dense.reshape(b, t_count, max_l)
         cols = []
-        for t, (m, sp) in enumerate(zip(self.members, self.specs)):
-            ids_t = dense[:, t, :]
-            ids_t = jnp.where(ids_t >= 0, ids_t,
-                              jnp.asarray(sp.null_row, ids_t.dtype))
-            red = m.reduce_dense(sp, ids_t)
-            # round through the member dtype exactly like the member's
-            # own lookup_bags does, so grouped dispatch stays bit-equal
-            # to the per-table loop on low-precision members too
-            red = red.astype(m.out_dtype).astype(jnp.float32)
-            if sp.dim < spec.dim:
-                red = jnp.pad(red, ((0, 0), (0, spec.dim - sp.dim)))
-            cols.append(red)
-        return jnp.stack(cols, axis=1).reshape(n_bags, spec.dim)
+        with obs_stage("gather_reduce"):
+            for t, (m, sp) in enumerate(zip(self.members, self.specs)):
+                ids_t = dense[:, t, :]
+                ids_t = jnp.where(ids_t >= 0, ids_t,
+                                  jnp.asarray(sp.null_row, ids_t.dtype))
+                red = m.reduce_dense(sp, ids_t)
+                # round through the member dtype exactly like the
+                # member's own lookup_bags does, so grouped dispatch
+                # stays bit-equal to the per-table loop on low-precision
+                # members too
+                red = red.astype(m.out_dtype).astype(jnp.float32)
+                if sp.dim < spec.dim:
+                    red = jnp.pad(red, ((0, 0), (0, spec.dim - sp.dim)))
+                cols.append(red)
+            return jnp.stack(cols, axis=1).reshape(n_bags, spec.dim)
 
     def reduce_fixed_ids(self, spec, indices):
         b, t, l = indices.shape

@@ -175,6 +175,7 @@ def embedding_bag(table: jax.Array, indices: jax.Array, *, bd: int = 2048,
         )
         fn = pl.pallas_call(
             functools.partial(_bag_kernel, n_l=l, tab=tab, out=out),
+            name="embedding_bag",
             grid_spec=grid_spec,
             out_shape=out.shape(e - s, d, table.dtype),
             compiler_params=arbitrary(3),
@@ -262,6 +263,7 @@ def sparse_lengths_sum(table: jax.Array, indices: jax.Array,
         )
         fn = pl.pallas_call(
             functools.partial(_ragged_kernel, max_l=max_l, tab=tab, out=out),
+            name="sparse_lengths_sum",
             grid_spec=grid_spec,
             out_shape=out.shape(e - s, d, table.dtype),
             compiler_params=arbitrary(3),
@@ -353,6 +355,7 @@ def sls_grad_table(g: jax.Array, indices: jax.Array, offsets: jax.Array, *,
     )
     fn = pl.pallas_call(
         functools.partial(_grad_kernel, n=c, grad=grad, tab=tab),
+        name="sls_grad_table",
         grid_spec=grid_spec,
         out_shape=tab.shape(n_rows, d, g.dtype),
         # operand 4 = the table (after 3 scalar-prefetch operands and g)

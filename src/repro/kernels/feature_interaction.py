@@ -36,6 +36,7 @@ def interaction(x: jax.Array, *, bb: int = 64,
     grid = (pl.cdiv(b, bb),)
     return pl.pallas_call(
         _interact_kernel,
+        name="interaction",
         grid=grid,
         in_specs=[pl.BlockSpec((bb, f, d), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((bb, f, f), lambda i: (i, 0, 0)),

@@ -87,6 +87,7 @@ def fused_segment_sum(table: jax.Array, dense_ids: jax.Array, *,
         )
         fn = pl.pallas_call(
             functools.partial(_fused_kernel, max_l=max_l, tab=tab, out=out),
+            name="fused_segment_sum",
             grid_spec=grid_spec,
             out_shape=out.shape(e - s, d, jnp.float32),
             compiler_params=arbitrary(3),
@@ -156,6 +157,7 @@ def fused_cached_segment_sum(hot_rows: jax.Array, arena: jax.Array,
         fn = pl.pallas_call(
             functools.partial(_cached_kernel, max_l=max_l, hot=hot, tab=tab,
                               out=out),
+            name="fused_cached_segment_sum",
             grid_spec=grid_spec,
             out_shape=out.shape(e - s, d, jnp.float32),
             compiler_params=arbitrary(3),
@@ -228,6 +230,7 @@ def fused_int4_segment_sum(packed: jax.Array, scales: jax.Array,
         fn = pl.pallas_call(
             functools.partial(_int4_kernel, max_l=max_l, tab=tab, sc=sc,
                               out=out),
+            name="fused_int4_segment_sum",
             grid_spec=grid_spec,
             out_shape=[out.shape(e - s, p, jnp.float32)] * 2,
             compiler_params=arbitrary(3),

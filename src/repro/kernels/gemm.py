@@ -60,6 +60,7 @@ def gemm(x: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
     return pl.pallas_call(
         functools.partial(_gemm_kernel, n_k=grid[2]),
+        name="gemm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -7,10 +7,12 @@ Three layers, one bundle:
   every sample), with JSON ``snapshot()`` and Prometheus-style
   ``exposition()``.
 * :mod:`repro.obs.tracing` — ``Tracer`` for nested per-request spans
-  through the serving pipeline, plus the jit-side ``stage()`` /
-  ``step_annotation()`` hooks that line our spans up with XLA profiles
-  (``jax.named_scope`` + ``jax.profiler.TraceAnnotation``). Off by
-  default with a zero-cost null path.
+  through the serving pipeline and the trainer (each enabled span is
+  also a ``jax.profiler.TraceAnnotation``, and backend compiles are
+  attributed to the span that caused them), plus the jit-side
+  ``stage()`` / ``step_annotation()`` hooks that line our spans up with
+  XLA profiles (``jax.named_scope`` + ``jax.profiler.TraceAnnotation``).
+  Off by default with a zero-cost null path.
 * :mod:`repro.obs.events` — ``EventLog`` for the versioned-swap
   protocol (swaps, rebuilds, refreshes, stale rejections) with
   per-version hit-rate attribution.
@@ -68,7 +70,7 @@ class Telemetry:
         self.enabled = bool(metrics)
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = Tracer(enabled=tracing and self.enabled,
-                             max_spans=max_spans)
+                             max_spans=max_spans, registry=self.registry)
         self.events = EventLog(max_events=max_events)
         self.device_stages = bool(device_stages) and self.enabled
 
@@ -77,8 +79,8 @@ class Telemetry:
         """The uninstrumented configuration (obs_overhead baseline)."""
         return cls(metrics=False)
 
-    def span(self, name: str, attrs=None):
-        return self.tracer.span(name, attrs)
+    def span(self, name: str, attrs=None, *, step_num=None):
+        return self.tracer.span(name, attrs, step_num=step_num)
 
     def emit(self, kind: str, version=None, **attrs):
         if not self.enabled:

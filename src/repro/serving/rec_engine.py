@@ -37,8 +37,9 @@ SLAs) over the ragged production sparse path:
   the old unbounded ``latencies``/``batch_sizes`` lists survive only as
   ring-backed compatibility properties), per-request spans through
   ``enqueue → batch → bucket_pad → forward → respond`` (or the per-stage
-  split below), and a structured event log of the swap protocol with
-  per-version hit-rate attribution
+  split below; continuous batching traces ``dispatch → bucket_pad`` and
+  ``settle → settle_wait, respond``), and a structured event log of the
+  swap protocol with per-version hit-rate attribution
   (``telemetry.events.hit_rate_by_version()``).
 
   Hit-rate accounting never adds a device sync to the hot path: the
@@ -61,6 +62,7 @@ SLAs) over the ragged production sparse path:
 """
 from __future__ import annotations
 
+import itertools
 import time
 import warnings
 from collections import deque
@@ -175,6 +177,9 @@ class InflightBatch:
     bucket: int
     downgraded: bool
     dispatched_mono: float
+    seq: int = 0                        # the engine's dispatch number: the
+    #                                     ``batch`` attr of its dispatch and
+    #                                     settle spans
 
 
 _STAGE_NAMES = ("sparse_lookup", "interaction", "mlp")
@@ -284,6 +289,7 @@ class RecEngine:
         self._batch_ring: deque = deque(
             maxlen=max(1024, auto_tune_after or 0))
         self._batches_seen = 0
+        self._dispatch_seq = itertools.count()
 
         if source is None:
             source = "ragged"
@@ -951,8 +957,10 @@ class RecEngine:
             r.downgraded = downgraded
             if tel.enabled:
                 self._qwait_hist.record((now_m - r.submitted_mono) * 1e3)
+        seq = next(self._dispatch_seq)
         with tel.span("dispatch", {"batch_size": len(reqs),
-                                   "bucket": bucket, "path": kind}):
+                                   "bucket": bucket, "path": kind,
+                                   "batch": seq}):
             if not downgraded:
                 self._stage_batch(reqs)  # host-cold residency guarantee
             with tel.span("bucket_pad"):
@@ -964,7 +972,8 @@ class RecEngine:
                 if tel.enabled and self.layout != "fixed":
                     self._dispatch_hit_probe(batch, n_valid)
         return InflightBatch(reqs=reqs, probs=probs, bucket=bucket,
-                             downgraded=downgraded, dispatched_mono=now_m)
+                             downgraded=downgraded, dispatched_mono=now_m,
+                             seq=seq)
 
     def settle(self, ib: InflightBatch) -> int:
         """Block on an in-flight batch's device result and respond.
@@ -973,19 +982,23 @@ class RecEngine:
         dispatch/settle pair — by settle time the futures of a deep
         enough pipeline completed long ago, so it is a read, not a
         stall. Records end-to-end latency (monotonic) and the
-        dispatch-to-settle service time per path.
+        dispatch-to-settle service time per path. Traced, ``settle_wait``
+        is the host blocked on the device and ``respond`` its own work.
         """
         tel = self.telemetry
-        with tel.span("settle", {"batch_size": len(ib.reqs)}):
-            probs = np.asarray(ib.probs)
-            done = time.time()
-            done_m = time.monotonic()
-            for i, r in enumerate(ib.reqs):
-                r.prob = float(probs[i])
-                r.finished_at = done
-                if tel.enabled:
-                    self._lat_hist.record((done_m - r.submitted_mono)
-                                          * 1e3)
+        with tel.span("settle", {"batch_size": len(ib.reqs),
+                                 "batch": ib.seq}):
+            with tel.span("settle_wait"):
+                probs = np.asarray(ib.probs)
+            with tel.span("respond"):
+                done = time.time()
+                done_m = time.monotonic()
+                for i, r in enumerate(ib.reqs):
+                    r.prob = float(probs[i])
+                    r.finished_at = done
+                    if tel.enabled:
+                        self._lat_hist.record(
+                            (done_m - r.submitted_mono) * 1e3)
         self.served += len(ib.reqs)
         if tel.enabled:
             self._c_served.inc(len(ib.reqs))

@@ -182,6 +182,9 @@ class SlaScheduler:
     ``rec_downgraded_total`` / ``rec_refills_total``, the shared
     ``rec_queue_depth`` gauge, and ``shed`` / ``downgrade`` / ``drain``
     events — every shed request is accounted for by exactly one event.
+    With tracing on, each turn is a ``pump`` span holding a ``plan``
+    span (the walk over the queue) and the engine's ``dispatch`` /
+    ``settle`` spans.
     """
 
     def __init__(self, engine: RecEngine,
@@ -352,13 +355,17 @@ class SlaScheduler:
         queue) settle one in-flight batch early so responses never wait
         for the next arrival.
         """
+        tel = self.telemetry
         settled = 0
-        while len(self._inflight) >= self.pipeline_depth:
-            settled += self._settle_one()
-        if self._queue:
-            self._apply(self._plan())
-        elif self._inflight:
-            settled += self._settle_one()
+        with tel.span("pump"):
+            while len(self._inflight) >= self.pipeline_depth:
+                settled += self._settle_one()
+            if self._queue:
+                with tel.span("plan", {"queued": len(self._queue)}):
+                    plan = self._plan()
+                self._apply(plan)
+            elif self._inflight:
+                settled += self._settle_one()
         return settled
 
     def drain(self) -> int:

@@ -249,14 +249,36 @@ class OnlineTrainer:
     # -- training ----------------------------------------------------------
 
     def train_step(self, batch: Dict) -> float:
-        """One optimizer step; maintains the cache protocol as a side effect."""
-        if self.cache_cfg is not None:   # the histogram only feeds rebuilds
-            self.observe(batch)
-        batch_dev = {k: jnp.asarray(v) for k, v in batch.items()
-                     if k in ("dense", "indices", "offsets", "labels")}
-        self.params, self.opt_state, loss, rows = self._step(
-            self.params, self.opt_state, batch_dev)
-        self.steps += 1
+        """One optimizer step; maintains the cache protocol as a side effect.
+
+        Traced, the step is a ``train_step`` span (a profiler step
+        marker) holding ``feed`` (histogram + host-to-device copy),
+        ``step`` (the jitted call's enqueue), ``rows`` (touched-row,
+        patch and rebuild handling) and ``loss_sync`` (the host waiting
+        for the step's loss)."""
+        tel = self.telemetry
+        with tel.span("train_step", step_num=self.steps + 1):
+            with tel.span("feed"):
+                if self.cache_cfg is not None:   # only rebuilds use it
+                    self.observe(batch)
+                batch_dev = {k: jnp.asarray(v) for k, v in batch.items()
+                             if k in ("dense", "indices", "offsets",
+                                      "labels")}
+            with tel.span("step"):
+                self.params, self.opt_state, loss, rows = self._step(
+                    self.params, self.opt_state, batch_dev)
+            self.steps += 1
+            with tel.span("rows"):
+                self._after_step(rows)
+            with tel.span("loss_sync"):
+                loss = float(loss)
+        self.losses.append(loss)
+        if self.telemetry.enabled:
+            self._c_steps.inc()
+            self._g_loss.set(loss)
+        return loss
+
+    def _after_step(self, rows) -> None:
         if self._dirty_q is not None:
             # the null/fill rows ride along harmlessly: re-quantizing an
             # all-zero row is an exact no-op
@@ -274,12 +296,6 @@ class OnlineTrainer:
         if self.cache_cfg is not None \
                 and self.steps % self.cache_cfg.refresh_every == 0:
             self.rebuild_cache()
-        loss = float(loss)
-        self.losses.append(loss)
-        if self.telemetry.enabled:
-            self._c_steps.inc()
-            self._g_loss.set(loss)
-        return loss
 
     def train(self, batches: Iterable[Dict]) -> list:
         for batch in batches:
@@ -566,13 +582,30 @@ class OnlineGroupTrainer:
     # -- training ----------------------------------------------------------
 
     def train_step(self, batch: Dict) -> float:
-        """One optimizer step; per-table write-through patch rides along."""
-        self.observe(batch)
-        batch_dev = {k: jnp.asarray(v) for k, v in batch.items()
-                     if k in ("dense", "indices", "offsets", "labels")}
-        self.params, self.opt_state, loss, touched = self._step(
-            self.params, self.opt_state, batch_dev)
-        self.steps += 1
+        """One optimizer step; per-table write-through patch rides along.
+        Traced with the same spans as ``OnlineTrainer.train_step``."""
+        tel = self.telemetry
+        with tel.span("train_step", step_num=self.steps + 1):
+            with tel.span("feed"):
+                self.observe(batch)
+                batch_dev = {k: jnp.asarray(v) for k, v in batch.items()
+                             if k in ("dense", "indices", "offsets",
+                                      "labels")}
+            with tel.span("step"):
+                self.params, self.opt_state, loss, touched = self._step(
+                    self.params, self.opt_state, batch_dev)
+            self.steps += 1
+            with tel.span("rows"):
+                self._after_step(touched)
+            with tel.span("loss_sync"):
+                loss = float(loss)
+        self.losses.append(loss)
+        if self.telemetry.enabled:
+            self._c_steps.inc()
+            self._g_loss.set(loss)
+        return loss
+
+    def _after_step(self, touched) -> None:
         for t, rows in enumerate(touched):
             if self._dirty_q[t] is not None:
                 self._dirty_q[t][np.asarray(rows)] = True
@@ -586,12 +619,6 @@ class OnlineGroupTrainer:
                     self.specs[t].null_row, rows)
         if self.steps % self.refresh_every == 0:
             self.rebuild()
-        loss = float(loss)
-        self.losses.append(loss)
-        if self.telemetry.enabled:
-            self._c_steps.inc()
-            self._g_loss.set(loss)
-        return loss
 
     def train(self, batches: Iterable[Dict]) -> list:
         for batch in batches:

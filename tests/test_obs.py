@@ -225,11 +225,17 @@ def test_tracer_record_nests_pretimed_span_under_open_span():
 
 
 def test_tracer_memory_is_bounded():
+    """The ring keeps the newest spans and counts what it drops."""
     tr = Tracer(max_spans=8)
     for _ in range(40):
         with tr.span("s"):
             pass
-    assert len(tr.spans()) == 8
+    tr.record("r", 0.0, 1.0)
+    assert len(tr.spans()) == 8 and tr.dropped == 33
+    tr.clear()
+    with tr.span("s"):
+        pass
+    assert tr.dropped == 0
 
 
 def test_disabled_tracer_and_stage_return_shared_null():
@@ -244,7 +250,8 @@ def test_disabled_tracer_and_stage_return_shared_null():
 
 def test_stage_annotations_leave_compiled_ops_identical(setup):
     """Flipping annotations on must change metadata only: the op
-    histogram of the compiled ragged serve step is identical."""
+    histograms of the compiled ragged serve step and sparse train step
+    are identical, and the scopes below the stages reach the op names."""
     cfg, params, data = setup
     rb = data.ragged_batch(4, dist="poisson", mean_l=3, max_l=MAX_L,
                            pad_to=4 * cfg.n_tables * MAX_L)
@@ -252,20 +259,99 @@ def test_stage_annotations_leave_compiled_ops_identical(setup):
              "indices": jnp.asarray(rb["indices"]),
              "offsets": jnp.asarray(rb["offsets"])}
     src = es.FpArena(params["arena"])
-    step = dlrm.make_ragged_serve_step(cfg, max_l=MAX_L)
+    train_batch = dict(batch, labels=jnp.asarray(rb["labels"]))
 
-    def op_hist():
-        return hlo_analysis.count_ops(
-            jax.jit(step).lower(params, batch, src).compile().as_text())
+    def compiled():
+        # fresh step functions, so JAX cannot reuse an earlier trace
+        step = dlrm.make_ragged_serve_step(cfg, max_l=MAX_L)
+        opt, train = dlrm.make_train_step_ragged(cfg, max_l=MAX_L,
+                                                 lr=1e-2)
+        return (
+            jax.jit(step).lower(params, batch, src).compile().as_text(),
+            jax.jit(train).lower(params, opt.init(params), train_batch)
+            .compile().as_text())
 
     assert not obs.stage_annotations_enabled()
-    off = op_hist()
+    off = compiled()
     obs.enable_stage_annotations(True)
     try:
-        on = op_hist()
+        on = compiled()
     finally:
         obs.enable_stage_annotations(False)
-    assert on == off
+    assert [hlo_analysis.count_ops(t) for t in on] == \
+        [hlo_analysis.count_ops(t) for t in off]
+    serve_on, train_on = on
+    for scope in ("relayout", "gather_reduce"):
+        assert f"/emb_lookup/{scope}/" in serve_on
+        assert f"/emb_lookup/{scope}/" in train_on
+    for scope in ("sparse_update", "dense_update"):
+        assert f"/{scope}/" in train_on
+    assert not any(f"/{scope}/" in t for t in off for scope in
+                   ("relayout", "gather_reduce", "sparse_update",
+                    "dense_update"))
+
+
+def test_enabled_spans_land_on_the_profiler_trace(tmp_path):
+    """An enabled span is also a host event of a jax.profiler trace, on
+    the device's clock, with its scalar attrs as stats; a disabled
+    tracer adds nothing."""
+    from jax.profiler import ProfileData
+
+    tr, off = Tracer(), Tracer(enabled=False)
+    assert off.span("hidden") is _NULL
+    x = jnp.ones(3)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("outer", {"batch": 7, "arr": [1]}):
+            with tr.span("inner"), off.span("hidden"):
+                (x + 1).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    events = {ev.name: ev
+              for plane in ProfileData.from_file(str(pb)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events}
+    assert "hidden" not in events
+    outer, inner = events["outer"], events["inner"]
+    assert dict(outer.stats) == {"batch": 7}
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= \
+        outer.start_ns + outer.duration_ns
+    assert [s.name for s in tr.spans() if s.name != "compile"] == \
+        ["inner", "outer"]
+
+
+def test_compiles_are_attributed_to_the_innermost_open_span():
+    from jax._src import monitoring
+
+    from repro.obs import tracing
+
+    tel = obs.Telemetry(tracing=True)
+    tr = tel.tracer
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = jnp.ones(17)
+    with tel.span("outer"):
+        with tel.span("step"):
+            f(x).block_until_ready()                # compiles
+        f(x).block_until_ready()                    # cached: no compile
+    (c,) = tr.spans("compile")
+    (step,) = tr.spans("step")
+    assert c.parent_id == step.span_id and c.attrs["span"] == "step"
+    assert step.start <= c.start <= c.end <= step.end
+    counters = tel.registry.snapshot()["counters"]
+    assert counters == {**counters, 'compiles_total{span="step"}': 1.0}
+    assert 'compiles_total{span="outer"}' not in counters
+    # a disabled tracer records nothing; the listener is there only
+    # while some enabled tracer is
+    tr.enabled = False
+    with tel.span("step"):
+        jax.jit(lambda x: x - 2.0)(x).block_until_ready()
+    assert len(tr.spans("compile")) == 1
+    listening = tracing._on_compile in \
+        monitoring.get_event_duration_listeners()
+    assert listening == bool(list(tracing._WATCHED))
+    assert tr not in list(tracing._WATCHED)
 
 
 # ---------------------------------------------------------------------------
@@ -460,7 +546,9 @@ def test_engine_spans_cover_the_serving_pipeline(setup):
     assert step.attrs == {"batch_size": 4, "bucket": 4}
     children = {s.name for s in tr.spans()
                 if s.parent_id == step.span_id}
-    assert children == {"batch", "bucket_pad", "forward", "respond"}
+    # the unwarmed engine compiles its hit probe inside this first step
+    assert children == {"batch", "bucket_pad", "forward", "respond",
+                        "compile"}
     assert len(tr.spans("enqueue")) == 4             # one per submit
 
 
@@ -535,3 +623,69 @@ def test_online_trainer_emits_rebuild_events_and_metrics():
     assert reg.gauge("train_cache_version").value == trainer.version
     assert reg.gauge("train_loss").value == pytest.approx(
         trainer.losses[-1])
+
+
+def _children(tr, span):
+    return [s.name for s in sorted(tr.spans(), key=lambda s: s.start)
+            if s.parent_id == span.span_id]
+
+
+def test_online_trainer_step_spans(tmp_path):
+    """Traced, a training step is train_step -> feed, step, rows,
+    loss_sync, in that order, and its profiler event is a step marker."""
+    from jax.profiler import ProfileData
+
+    from repro.training import OnlineTrainer
+
+    cfg = DLRM_SMOKE
+    params = dlrm.init(jax.random.PRNGKey(0), cfg)
+    data = DLRMSynthetic(cfg, seed=3)
+    tel = obs.Telemetry(tracing=True)
+    trainer = OnlineTrainer(cfg, params, max_l=MAX_L, lr=1e-2,
+                            telemetry=tel)
+    pad = 16 * cfg.n_tables * MAX_L
+    batches = [data.ragged_batch(16, dist="poisson", mean_l=3, max_l=MAX_L,
+                                 pad_to=pad) for _ in range(3)]
+    trainer.train_step(batches[0])
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for b in batches[1:]:
+            trainer.train_step(b)
+    finally:
+        jax.profiler.stop_trace()
+    tr = tel.tracer
+    steps = tr.spans("train_step")
+    assert [s.attrs for s in steps] == [{}, {}, {}]
+    for st in steps:
+        assert _children(tr, st) == ["feed", "step", "rows", "loss_sync"]
+    # the first step compiled inside its ``step`` span
+    (c,) = tr.spans("compile")
+    assert c.parent_id == tr.spans("step")[0].span_id
+    (pb,) = tmp_path.rglob("*.xplane.pb")
+    marks = [dict(ev.stats).get("step_num")
+             for plane in ProfileData.from_file(str(pb)).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "train_step"]
+    assert sorted(marks) == [2, 3]
+
+
+def test_online_group_trainer_step_spans():
+    from repro.configs.dlrm import DLRM_HET_SMOKE
+    from repro.training import OnlineGroupTrainer
+
+    cfg = DLRM_HET_SMOKE
+    params = dlrm.init(jax.random.PRNGKey(0), cfg)
+    tel = obs.Telemetry(tracing=True)
+    tr = OnlineGroupTrainer(cfg, params, max_l=MAX_L,
+                            plans=dlrm.table_plans(cfg), lr=1e-2,
+                            telemetry=tel)
+    data = DLRMSynthetic(cfg, seed=5)
+    for _ in range(2):
+        tr.train_step(data.ragged_batch(4, dist="poisson", mean_l=3,
+                                        max_l=MAX_L,
+                                        pad_to=4 * cfg.n_tables * MAX_L))
+    steps = tel.tracer.spans("train_step")
+    assert len(steps) == 2
+    for st in steps:
+        assert _children(tel.tracer, st) == \
+            ["feed", "step", "rows", "loss_sync"]

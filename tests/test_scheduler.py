@@ -446,3 +446,43 @@ def test_scheduler_end_to_end_on_real_engine(served_engine):
     assert s["submitted"] == 32 and 0.0 <= s["shed_frac"] <= 1.0
     if sched.served:
         assert s["n"] == sched.served
+
+
+def test_scheduler_turn_spans_nest_and_join_batches(served_engine):
+    """Traced, each turn is pump -> plan / dispatch / settle, settle
+    splits into settle_wait (blocked on the device) and respond, and a
+    batch's dispatch and settle spans carry the same ``batch``."""
+    cfg, _, reqs = served_engine
+    params = dlrm.init(jax.random.PRNGKey(0), cfg)
+    eng = RecEngine(cfg, params, source="ragged", max_l=6, max_batch=8,
+                    buckets=(2, 8), telemetry=obs.Telemetry(tracing=True))
+    sched = SlaScheduler(eng, SlaPolicy(allow_shed=False,
+                                        allow_downgrade=False))
+    sched.warmup(calibrate=False)
+    for r in reqs:
+        r.submitted_mono = time.monotonic()
+        sched.submit(r)
+        sched.pump()
+    while sched.inflight:
+        sched.pump()
+    tr = eng.telemetry.tracer
+    by_id = {s.span_id: s for s in tr.spans()}
+
+    def parent(s):
+        return by_id[s.parent_id].name if s.parent_id else None
+
+    assert {parent(s) for s in tr.spans("plan")} == {"pump"}
+    assert {parent(s) for s in tr.spans("dispatch")} == {"pump"}
+    assert {parent(s) for s in tr.spans("settle")} == {"pump"}
+    assert {parent(s) for s in tr.spans("bucket_pad")} == {"dispatch"}
+    assert {parent(s) for s in tr.spans("settle_wait")} == {"settle"}
+    assert {parent(s) for s in tr.spans("respond")} == {"settle"}
+    assert all(s.attrs["queued"] >= 1 for s in tr.spans("plan"))
+    dispatched = {s.attrs["batch"]: s for s in tr.spans("dispatch")}
+    settled = {s.attrs["batch"]: s for s in tr.spans("settle")}
+    assert dispatched.keys() == settled.keys()
+    assert len(dispatched) == len(tr.spans("dispatch")) >= 3
+    for k, d in dispatched.items():
+        assert d.end <= settled[k].start
+        assert d.attrs["batch_size"] == settled[k].attrs["batch_size"]
+    assert tr.dropped == 0
