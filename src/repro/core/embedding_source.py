@@ -124,7 +124,7 @@ class EmbeddingSource:
     ``shard_reduce_fixed``) are only required of sources that can sit
     inside ``ShardedArena``. ``reduce_bags`` / ``reduce_fixed_ids`` are
     the per-table-id halves of the two entry points; their defaults
-    flatten against the uniform arena layout, and only
+    map the ids into the uniform arena layout, and only
     ``TableGroupSource`` (whose tables have no shared layout to flatten
     into) overrides them.
     """
@@ -136,14 +136,12 @@ class EmbeddingSource:
     def reduce_bags(self, spec: se.ArenaSpec, indices: jax.Array,
                     offsets: jax.Array, *, max_l: int) -> jax.Array:
         """(N,) per-table row ids + (n_bags+1,) offsets -> f32
-        (n_bags, D). Default: flatten into the uniform arena layout,
-        relayout once, reduce fused. The two halves carry the scopes
-        ``relayout`` and ``gather_reduce``, so a device trace splits the
-        lookup's time between them."""
+        (n_bags, D). Default: relayout once into arena row ids of the
+        uniform layout (``se.dense_arena_ids``), reduce fused. The two
+        halves carry the scopes ``relayout`` and ``gather_reduce``, so a
+        device trace splits the lookup's time between them."""
         with obs_stage("relayout"):
-            flat = se.flatten_ragged_indices(spec, indices, offsets)
-            dense = se.ragged_dense_ids(flat, offsets, max_l=max_l,
-                                        fill=spec.null_row)
+            dense = se.dense_arena_ids(spec, indices, offsets, max_l=max_l)
         with obs_stage("gather_reduce"):
             return self.reduce_dense(spec, dense)
 

@@ -190,15 +190,43 @@ def ragged_dense_ids(indices: jax.Array, offsets: jax.Array, *,
     length (the same contract the Pallas grid already imposes); with
     `fill` pointing at an always-zero row the result needs no masking.
     """
-    n = indices.shape[0]
     n_bags = offsets.shape[0] - 1
-    if n == 0 or max_l == 0:
+    if indices.shape[0] == 0 or max_l == 0:
         return jnp.full((n_bags, max_l), fill, indices.dtype)
+    dense, valid = _ragged_slots(indices, offsets, max_l)
+    return jnp.where(valid, dense, jnp.asarray(fill, indices.dtype))
+
+
+def _ragged_slots(indices: jax.Array, offsets: jax.Array, max_l: int):
+    """(ids, valid) of the (n_bags, max_l) relayout: the stream's id at
+    slot j of bag b (clamped into the stream where the slot is outside
+    the bag), and whether the slot lies inside the bag."""
     pos = offsets[:-1, None] + jnp.arange(max_l, dtype=offsets.dtype)
     valid = pos < offsets[1:, None]
-    safe = jnp.minimum(jnp.where(valid, pos, 0), n - 1)
-    dense = jnp.take(indices, safe, axis=0)
-    return jnp.where(valid, dense, jnp.asarray(fill, indices.dtype))
+    safe = jnp.minimum(jnp.where(valid, pos, 0), indices.shape[0] - 1)
+    return jnp.take(indices, safe, axis=0), valid
+
+
+def dense_arena_ids(spec: ArenaSpec, indices: jax.Array,
+                    offsets: jax.Array, *, max_l: int) -> jax.Array:
+    """Per-table ragged stream -> (n_bags, max_l) arena row ids.
+
+    The relayout of ``ragged_dense_ids`` with each row's table base
+    added, and every short or padded slot at the zero null row. Row b of
+    the matrix IS bag b, so its table is ``b % n_tables`` (the (sample,
+    table) row-major convention of ``ragged_position_tables``) and no
+    position has to search for its bag. Bit-equal to
+    ``ragged_dense_ids(flatten_ragged_indices(spec, indices, offsets),
+    offsets, max_l=max_l, fill=spec.null_row)``.
+    """
+    n_bags = offsets.shape[0] - 1
+    null = jnp.asarray(spec.null_row, indices.dtype)
+    if indices.shape[0] == 0 or max_l == 0:
+        return jnp.full((n_bags, max_l), null, indices.dtype)
+    dense, valid = _ragged_slots(indices, offsets, max_l)
+    base = ((jnp.arange(n_bags, dtype=indices.dtype) % spec.n_tables)
+            * spec.rows_per_table)
+    return jnp.where(valid, dense + base[:, None], null)
 
 
 def dense_partial_reduce(arena_shard: jax.Array, dense: jax.Array,

@@ -123,7 +123,10 @@ def test_hlo_gate_fused_forwards_are_scatter_free_one_pass():
       split is real state;
     - the grouped forward runs one small dense reduction per member (T
       reduces over (B, max_l) blocks) with no dynamic loop, instead of T
-      reductions over the full interleaved stream."""
+      reductions over the full interleaved stream;
+    - no forward runs a dynamic loop: the relayout reads each slot's
+      table off its row of the dense id matrix instead of searching
+      for each stream position's bag."""
     import dataclasses
     es, se, spec, arena, idx, off, max_l = _sparse_fixture()
     t = spec.n_tables
@@ -172,6 +175,10 @@ def test_hlo_gate_fused_forwards_are_scatter_free_one_pass():
     # outside the backward and get DCE'd)
     assert c_c.get("reduce", 0) == c_fp.get("reduce", 0) == 1, (c_fp, c_c)
     assert c_c.get("gather", 0) == c_fp.get("gather", 0), (c_fp, c_c)
+    # plain and cached: each slot's table is its row of the dense id
+    # matrix, so no per-position bag search (a while loop) is left
+    assert c_fp.get("while", 0) == 0, c_fp
+    assert c_c.get("while", 0) == 0, c_c
     # grouped: per-member dense reductions, no dynamic loop over the
     # stream (the T-full-walk shape lowered with while/scatter)
     assert c_g.get("reduce", 0) == t, c_g

@@ -175,6 +175,84 @@ def test_flatten_ragged_routes_padding_to_null(rng):
     assert (flat[4:] == spec.null_row).all()
 
 
+# (n_tables, samples, max_l, bag lengths or None for random ones, padded
+# tail): the edges of the relayout the default reduce_bags runs
+_RELAYOUT_CASES = {
+    "t1_empty_runs": (1, 9, 4, [0, 0, 3, 4, 0, 0, 0, 2, 0], 5),
+    "t1_all_empty": (1, 4, 3, [0, 0, 0, 0], 4),
+    "t1_empty_stream": (1, 3, 2, [0, 0, 0], 0),
+    "t50_random_runs": (50, 2, 6, None, 7),
+    "t50_all_full": (50, 1, 3, [3] * 50, 0),
+    "t50_long_tail": (50, 1, 4, None, 260),
+}
+
+
+def _relayout_case(name):
+    t, b, max_l, lens, pad = _RELAYOUT_CASES[name]
+    rng = np.random.RandomState(len(name))
+    spec = se.ArenaSpec(t, 20, 8)
+    if lens is None:
+        lens = rng.randint(0, max_l + 1, b * t)
+        lens[3:7] = 0                 # a run of empty bags
+        lens[10] = max_l              # a full bag
+        lens[-1] = 0                  # a trailing empty bag
+    off = np.r_[0, np.cumsum(lens)].astype(np.int32)
+    idx = rng.randint(0, spec.rows_per_table,
+                      int(off[-1]) + pad).astype(np.int32)
+    return spec, idx, off, max_l
+
+
+def _old_order_dense(spec, idx, off, max_l):
+    """numpy oracle of the relayout's old order: each stream position's
+    bag by binary search, that bag's table base added, then the
+    (n_bags, max_l) relayout with every empty slot at the null row."""
+    n_bags = len(off) - 1
+    seg = np.searchsorted(off[1:], np.arange(len(idx)), side="right")
+    table = np.minimum(seg, n_bags - 1) % spec.n_tables
+    flat = np.where(seg < n_bags, idx + table * spec.rows_per_table,
+                    spec.null_row).astype(idx.dtype)
+    dense = np.full((n_bags, max_l), spec.null_row, idx.dtype)
+    for k in range(n_bags):
+        run = flat[off[k]:off[k + 1]]
+        dense[k, :len(run)] = run
+    return dense
+
+
+def _relayout_source(kind, arena, spec, idx, off):
+    if kind == "fp":
+        return es.FpArena(arena)
+    if kind == "int8":
+        return es.QuantizedArena.from_arena(arena)
+    counts = se.trace_row_counts(spec, jnp.asarray(idx), jnp.asarray(off))
+    cache = se.build_hot_cache(arena, spec, counts, 8)
+    return es.CachedSource(cache, es.FpArena(arena),
+                           coherent=kind == "cached_coherent")
+
+
+@pytest.mark.parametrize("kind", ["fp", "int8", "cached_coherent",
+                                  "cached_incoherent"])
+@pytest.mark.parametrize("case", sorted(_RELAYOUT_CASES))
+def test_dense_arena_ids_match_the_searched_order(case, kind):
+    """Taking each bag's table from its row of the dense id matrix gives
+    the same arena ids as searching each position's bag and flattening
+    first, bit for bit, and so the same lookup through every source that
+    inherits the default ``reduce_bags``."""
+    spec, idx, off, max_l = _relayout_case(case)
+    want = _old_order_dense(spec, idx, off, max_l)
+    got = se.dense_arena_ids(spec, jnp.asarray(idx), jnp.asarray(off),
+                             max_l=max_l)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    arena = se.init_arena(jax.random.PRNGKey(len(case)), spec, scale=1.0)
+    src = _relayout_source(kind, arena, spec, idx, off)
+    out = es.lookup_bags(src, spec, jnp.asarray(idx), jnp.asarray(off),
+                         max_l=max_l)
+    oracle = src.reduce_dense(spec, jnp.asarray(want)).reshape(
+        out.shape).astype(src.out_dtype)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
+
+
 # ---------------------------------------------------------------------------
 # hot-row cache
 # ---------------------------------------------------------------------------
