@@ -1,14 +1,13 @@
 """The comparison that decides ``correct``: what the timed path produced
 against the plain reference, each number against its limit from the
-configuration file. Runs after the program's state is freed."""
+configuration file. Runs after the program's state is freed; the
+reference is the configuration's model module's."""
 from __future__ import annotations
 
 import json
 import sys
 
 import numpy as np
-
-from bench import reference, weights
 
 # a leaf whose reference gradient is under this share of the median leaf's
 # moves by round-off alone under Adam, and is left out of the change
@@ -19,16 +18,15 @@ def _check(name: str, value: float, limit: float) -> dict:
     return {"name": name, "value": float(value), "limit": float(limit)}
 
 
-def serve(c: dict, seed: int, bodies, prob: np.ndarray, max_l: int) -> tuple:
+def serve(model, c: dict, seed: int, bodies, prob: np.ndarray,
+          max_l: int) -> tuple:
     """Checks of a serving run and the number of requests that failed:
     |served CTR - reference CTR| of every request served (nan where a
     request got no answer)."""
-    params = weights.make(c, seed)
     sel = np.nonzero(np.isfinite(prob))[0]
     err = np.full(prob.shape, np.nan)
-    err[sel] = np.abs(prob[sel].astype(np.float64) - reference.ctr(
-        c, params, bodies, sel, max_l=max_l).astype(np.float64))
-    del params
+    err[sel] = np.abs(prob[sel].astype(np.float64) - model.reference_ctr(
+        c, seed, bodies, sel, max_l).astype(np.float64))
     limit = c["limits"]["ctr_max_abs_err"]
     unanswered = int(np.sum(~np.isfinite(prob)))
     worst = float(np.nanmax(err)) if np.isfinite(err).any() else np.inf
@@ -74,8 +72,11 @@ def train_numbers(prog: dict, ref: dict) -> dict:
     }
 
 
-def train(c: dict, seed: int, prog: dict, batches) -> tuple:
-    ref = reference.train(c, seed, batches)
+def train(model, c: dict, seed: int, prog: dict, bodies,
+          max_l: int) -> tuple:
+    """Checks of a training run's readings against the reference's first
+    steps on the same ``bodies``, and how many failed."""
+    ref = model.reference_train(c, seed, bodies, max_l)
     keys = ("losses", "grad_norms", "change_norms")
     print("readings " + json.dumps({k: {"program": prog[k], "reference":
                                         ref[k]} for k in keys}),

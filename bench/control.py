@@ -25,47 +25,45 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 
 
-def serve_readings(c: dict, mix: dict, seed: int, seconds: float) -> dict:
+def serve_readings(model, c: dict, mix: dict, seed: int,
+                   seconds: float) -> dict:
     """The serving checks with the control's CTRs in the program's
     place."""
     import numpy as np
 
-    from bench import check, loadgen, reference, weights
+    from bench import check, loadgen
 
     due = loadgen.poisson_arrivals(mix["rate_qps"], seconds,
                                    mix["shape_seed"], seed)
     n_due = int(np.searchsorted(due, seconds))
-    bodies = loadgen.bodies(c, mix, len(due), seed)
-    params = weights.make(c, seed)
-    prob = reference.ctr(c, params, bodies, np.arange(n_due), "high",
-                         max_l=mix["bag"]["max"])
-    del params
-    checks, _ = check.serve(c, seed, bodies, prob, mix["bag"]["max"])
+    bodies = model.bodies(c, mix, len(due), seed)
+    max_l = loadgen.max_len(mix["bag"])
+    prob = model.reference_ctr(c, seed, bodies, np.arange(n_due), max_l,
+                               "high")
+    checks, _ = check.serve(model, c, seed, bodies, prob, max_l)
     return {ch["name"]: ch["value"] for ch in checks}
 
 
-def train_readings(c: dict, mix: dict, seed: int) -> dict:
+def train_readings(model, c: dict, mix: dict, seed: int) -> dict:
     """The training numbers of the control and of the half-batch fault,
     each against the reference."""
-    from bench import check, reference
+    from bench import check, loadgen
     from bench.train import CHECKED_STEPS, batch_bodies
 
-    batches = [reference.train_batch_of(b, mix["bag"]["max"])
-               for b in batch_bodies(c, mix, seed, CHECKED_STEPS)]
-    ref = reference.train(c, seed, batches)
-    ctl = reference.train(c, seed, batches, precision="high")
-    rows = reference.touched_rows(batches[0], c["n_tables"],
-                                  c["rows_per_table"])
-    half = reference.train(c, seed, [reference.half_batch(b, c["n_tables"])
-                                     for b in batches], grad_rows=rows)
+    bodies = batch_bodies(model, c, mix, seed, CHECKED_STEPS)
+    max_l = loadgen.max_len(mix["bag"])
+    ref = model.reference_train(c, seed, bodies, max_l)
+    ctl = model.reference_train(c, seed, bodies, max_l, "high")
+    half = model.reference_train(c, seed, bodies, max_l, half_batch=True)
     return {"control": check.train_numbers(ctl, ref),
             "half_batch": check.train_numbers(half, ref)}
 
 
 def readings(cell, seed: int, seconds: float) -> dict:
     if cell.traffic["kind"] == "open_loop":
-        return serve_readings(cell.config, cell.traffic, seed, seconds)
-    return train_readings(cell.config, cell.traffic, seed)
+        return serve_readings(cell.model, cell.config, cell.traffic, seed,
+                              seconds)
+    return train_readings(cell.model, cell.config, cell.traffic, seed)
 
 
 def main(argv=None) -> int:

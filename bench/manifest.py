@@ -1,10 +1,13 @@
 """Find a cell and everything it names, by name alone.
 
 ``BENCHMARK.json`` lists the cells, configurations and metrics. A cell's
-configuration is the JSON file its ``configs`` entry names; its traffic mix
-is ``<bench>/traffic/<traffic>.json``; every metric, end-to-end or per-layer,
+configuration is the JSON file its ``configs`` entry names; the model it
+runs is ``<bench>/models/<model>.py``, as the configuration's ``"model"``
+names it (``dlrm_dot`` where it names none); its traffic mix is
+``<bench>/traffic/<traffic>.json``; every metric, end-to-end or per-layer,
 is read by ``<bench>/metrics/<metric>.py``. Adding a cell, a mix, a
-configuration or a metric adds files and entries and edits nothing.
+configuration, a model or a metric adds files and entries and edits
+nothing.
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+DEFAULT_MODEL = "dlrm_dot"
 
 
 @dataclass
@@ -25,6 +29,7 @@ class Cell:
     name: str
     chips: int
     config: dict        # the configuration file's contents
+    model: object       # the configuration's model module
     traffic: dict       # the mix file's contents, plus its "name"
     metrics: list       # the BENCHMARK.json entries this run reports
 
@@ -67,17 +72,33 @@ def cell(name: str, trace: bool = False, root: Path = ROOT) -> Cell:
     with open(bench / "traffic" / f"{w['traffic']}.json") as f:
         traffic = dict(json.load(f), name=w["traffic"])
     return Cell(name=name, chips=int(w["chips"]), config=config,
-                traffic=traffic, metrics=reported(man, name, trace))
+                model=model(config, root), traffic=traffic,
+                metrics=reported(man, name, trace))
+
+
+def _module(kind: str, name: str, root: Path):
+    """``<bench>/<kind>/<name>.py``, loaded by path: the checkout's file,
+    whichever ``bench`` package is imported."""
+    path = root / load(root)["paths"][0] / kind / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str, root: Path = ROOT):
     """The ``read(run)`` function of ``<bench>/metrics/<metric>.py``."""
-    path = root / load(root)["paths"][0] / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric, root).read
+
+
+def model(config: dict, root: Path = ROOT):
+    """The model module of configuration ``config`` (see
+    ``bench/models/dlrm_dot.py`` for what one gives)."""
+    name = config.get("model", DEFAULT_MODEL)
+    if not NAME.match(name):
+        raise ValueError(f"model name {name!r} is not a name")
+    return _module("models", name, root)
 
 
 def peaks(device_kind: str, root: Path = ROOT) -> dict:

@@ -22,6 +22,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from bench.readers import _share
+
 RELAYOUT = "relayout"
 GATHER_KERNEL = "gather_reduce"
 SPARSE_UPDATE = "sparse_update"
@@ -42,12 +44,6 @@ def _scope_s(s, scope: str):
     if not s.in_scopes((scope,)).any():
         return None
     return s.scope_s((scope,), 0.0, np.inf)
-
-
-def _share(work, seconds, peaks):
-    if work is None or not seconds:
-        return None
-    return 100.0 * work.least_s(peaks) / seconds
 
 
 def relayout_ms_per_batch(run):
@@ -93,10 +89,11 @@ def unwrapped(s):
 
 def scope_seconds(s) -> dict:
     """Device seconds under each program scope (autodiff unwrapped),
-    with ``busy`` and ``outside`` (busy time under no scope)."""
+    with ``busy`` and ``outside`` (busy time under no scope), each summed
+    over the chips."""
     u = unwrapped(s)
     out = {name: u.scope_s((name,), 0.0, np.inf) for name in SCOPES}
-    out["busy"] = u.busy_s(0.0, np.inf)
+    out["busy"] = u.chips * u.busy_s(0.0, np.inf)
     out["outside"] = u.outside_s(SCOPES, 0.0, np.inf)
     return out
 
@@ -164,27 +161,12 @@ def host_spans(path: str) -> list:
                  for a, b, n, ln in events])
 
 
-def idle_gaps(s, window_s: float) -> list:
-    """Every device-idle interval ``(start, end)`` of the window (the
-    intervals behind ``Summary.idle_gaps``, which keeps their lengths
-    only)."""
-    order = np.argsort(s.starts)
-    gaps, t = [], 0.0
-    for a, b in zip(s.starts[order], s.ends[order]):
-        if a > t:
-            gaps.append((t, min(a, window_s)))
-        t = max(t, b)
-    if t < window_s:
-        gaps.append((t, window_s))
-    return [g for g in gaps if g[1] > g[0]]
-
-
 def idle_by_span(s, spans, k: int, window_s: float) -> list:
     """The ``k`` longest idle gaps, each ``[path, seconds]``: the path of
     the innermost program span covering more than half of it ("" where
     none does)."""
     out = []
-    for a, b in sorted(idle_gaps(s, window_s), key=lambda g: g[0] - g[1])[:k]:
+    for a, b in sorted(s.gaps(window_s), key=lambda g: g[0] - g[1])[:k]:
         # spans covering more than half of one gap nest in one another
         cover = [sp.path for sp in spans
                  if min(b, sp.end) - max(a, sp.start) > (b - a) / 2]

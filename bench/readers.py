@@ -58,6 +58,10 @@ def device_idle_pct(run):
 
 
 def _share(work, seconds, peaks):
+    """The least time of ``work`` at one chip's ``peaks`` over
+    ``seconds`` of device time summed over the chips: on several chips,
+    the least time at all their peaks together over the mean chip's
+    time."""
     if work is None or not seconds or seconds <= 0:
         return None
     return 100.0 * work.least_s(peaks) / seconds
@@ -65,12 +69,13 @@ def _share(work, seconds, peaks):
 
 def step_mfu_pct(run):
     """The least time of all the window's work over the device's busy time
-    (serving) or over the window (training)."""
+    (serving) or over the window (training), at the peaks of all the
+    chips."""
     if run.trace is None:
         return None
     t = (run.window_s if run.steps
          else run.trace.busy_s(0.0, np.inf))
-    return _share(run.total_work(), t, run.peaks)
+    return _share(run.total_work(), t * run.trace.chips, run.peaks)
 
 
 def gather_roofline_pct(run):

@@ -78,6 +78,19 @@ def compile_counter():
         jax.monitoring.unregister_event_duration_listener(listen)
 
 
+def chip_mesh(chips: int):
+    """A one-axis ``model`` mesh over the first ``chips`` devices, over
+    which the program row-shards its arena; None for one chip, which the
+    program runs unsharded."""
+    if chips == 1:
+        return None
+    devs = jax.devices()
+    if len(devs) < chips:
+        raise RuntimeError(f"the cell needs {chips} chips, JAX found "
+                           f"{len(devs)}")
+    return jax.sharding.Mesh(np.array(devs[:chips]), ("model",))
+
+
 def start_trace(log_dir: str) -> None:
     """Profile devices and the host's annotations, without Python's own
     function tracer (which would record every call the loop makes)."""

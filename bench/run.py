@@ -54,16 +54,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: dict,
     """Set up, measure, check. Returns the result line's fields."""
     import jax
 
-    from bench import check
-    from bench import xplane
-    from bench.record import clock
+    from bench import check, loadgen, xplane
+    from bench.record import chip_mesh, clock
 
     kind = cell.traffic["kind"]
+    mesh = chip_mesh(cell.chips)
+    max_l = loadgen.max_len(cell.traffic["bag"])
     prof = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
         if kind == "open_loop":
             from bench.serve import Serve
-            srv = Serve(cell.config, cell.traffic, seed, traced=trace)
+            srv = Serve(cell.model, cell.config, cell.traffic, seed,
+                        traced=trace, mesh=mesh)
             run, bodies, prob = srv.window(cell.traffic["rate_qps"],
                                            seconds, seed, prof)
             run.setup_s = srv.t0 - t_start
@@ -73,12 +75,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: dict,
             srv.close()
             del srv
             gc.collect()
-            checks, failed = check.serve(cell.config, seed, bodies, prob,
-                                         cell.traffic["bag"]["max"])
+            checks, failed = check.serve(cell.model, cell.config, seed,
+                                         bodies, prob, max_l)
             attempted = len(prob)
         elif kind == "closed_loop":
             from bench.train import CHECKED_STEPS, Train
-            tr = Train(cell.config, cell.traffic, seed)
+            tr = Train(cell.model, cell.config, cell.traffic, seed, mesh)
             prog = tr.first_steps()
             t0 = clock()
             run = tr.window(seconds, prof)
@@ -86,17 +88,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, peaks: dict,
             run.setup_s = t0 - t_start - tr.reading_s
             run.peak_bytes = peak_bytes(jax, cell.chips)
             hlo = tr.hlo_texts() if trace else []
-            batches = tr.reference_batches()
+            bodies = tr.checked_bodies()
             tr.close()
             del tr
             gc.collect()
-            checks, failed = check.train(cell.config, seed, prog, batches)
+            checks, failed = check.train(cell.model, cell.config, seed, prog,
+                                         bodies, max_l)
             attempted = CHECKED_STEPS + len(run.steps)
         else:
             raise ValueError(f"unknown traffic kind {kind!r}")
         run.peaks = peaks
         if trace:
-            run.trace = xplane.load_dir(prof, hlo)
+            run.trace = xplane.load_dir(prof, hlo, cell.chips)
     finally:
         if prof:
             shutil.rmtree(prof, ignore_errors=True)

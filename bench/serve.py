@@ -15,38 +15,30 @@ import gc
 import jax
 import numpy as np
 
-from bench import loadgen, weights, work
+from bench import loadgen
 from bench.record import (Run, annotate, clock, compile_counter,
                           start_trace)
 
 
-def dlrm_config(c: dict):
-    from repro.configs.base import DLRMConfig
-    return DLRMConfig(
-        name=c["name"], n_tables=c["n_tables"],
-        rows_per_table=c["rows_per_table"], emb_dim=c["emb_dim"],
-        lookups_per_table=c["lookups_per_table"],
-        dense_features=c["dense_features"],
-        bottom_mlp=tuple(c["bottom_mlp"]), top_mlp=tuple(c["top_mlp"]),
-        dtype=c["dtype"])
-
-
 class Serve:
     """The serving stack of one cell, warmed for every bucket its traffic
-    uses; ``window`` offers one open-loop load to it."""
+    uses; ``window`` offers one open-loop load to it. ``model`` is the
+    configuration's model module; on a ``mesh`` the engine's arena is
+    row-sharded over it."""
 
-    def __init__(self, c: dict, mix: dict, seed: int, *, traced: bool):
+    def __init__(self, model, c: dict, mix: dict, seed: int, *,
+                 traced: bool, mesh=None):
         from repro import obs
         from repro.serving import RecEngine
         from repro.serving.scheduler import SlaPolicy, SlaScheduler
 
-        self.c, self.mix = c, mix
-        params = weights.make(c, seed)
+        self.model, self.c, self.mix = model, c, mix
+        params = model.weights(c, seed, mesh)
         jax.block_until_ready(params)
         self.engine = RecEngine(
-            dlrm_config(c), params, source="ragged",
-            max_l=mix["bag"]["max"], max_batch=mix["max_batch"],
-            buckets=tuple(mix["buckets"]),
+            model.program_config(c), params, source="ragged",
+            max_l=loadgen.max_len(mix["bag"]), max_batch=mix["max_batch"],
+            buckets=tuple(mix["buckets"]), mesh=mesh,
             telemetry=obs.Telemetry(tracing=traced, max_spans=1 << 20))
         self.sched = SlaScheduler(
             self.engine, SlaPolicy(allow_shed=False, allow_downgrade=False),
@@ -86,7 +78,7 @@ class Serve:
 
     def requests(self, b: loadgen.Bodies):
         from repro.serving import RecRequest
-        t = self.c["n_tables"]
+        t = b.lens.shape[1]
         cuts = b.offsets[1:-1]
         per_bag = np.split(b.ids, cuts)
         return [RecRequest(rid=i, dense=b.dense[i],
@@ -103,7 +95,7 @@ class Serve:
         gc.disable()
         due = loadgen.poisson_arrivals(rate_qps, seconds,
                                        self.mix["shape_seed"], seed)
-        b = loadgen.bodies(self.c, self.mix, len(due), seed)
+        b = self.model.bodies(self.c, self.mix, len(due), seed)
         reqs = self.requests(b)
         gc.enable()
         n = len(reqs)
@@ -163,7 +155,7 @@ class Serve:
                   settle=self.t_settle[:n_due], batches=self.batches)
         for t, k, ids in self.batches:
             if t <= seconds:
-                run.add_work(work.serve_batch(self.c, ids, k))
+                run.add_work(self.model.serve_work(self.c, ids, k))
         spans = self.engine.telemetry.tracer.finished
         for s in spans:
             if s.name in ("dispatch", "settle"):

@@ -52,7 +52,7 @@ def main(argv=None) -> int:
     import numpy as np
 
     from bench import manifest, readers
-    from bench.record import percentile
+    from bench.record import chip_mesh, percentile
     from bench.serve import Serve
     from repro import obs
     from repro.launch.compile_cache import use_compile_cache
@@ -65,7 +65,8 @@ def main(argv=None) -> int:
     cell = manifest.cell(args.workload)
     jax.config.update("jax_default_matmul_precision",
                       cell.config["matmul_precision"])
-    srv = Serve(cell.config, cell.traffic, args.seed, traced=False)
+    srv = Serve(cell.model, cell.config, cell.traffic, args.seed,
+                traced=False, mesh=chip_mesh(cell.chips))
     rows, knee = [], None
     for k, rate in enumerate(knee_rates(args.lo, args.hi, args.steps)):
         run, _, prob = srv.window(rate, args.seconds, args.seed + 1 + k)

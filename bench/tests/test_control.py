@@ -7,7 +7,7 @@ import json
 
 import pytest
 
-from bench import control
+from bench import control, manifest
 from bench.tests.helpers import ROOT
 
 ROWS = 500
@@ -27,14 +27,15 @@ def mix(name: str) -> dict:
 def test_serving_control_fails_the_limit(seed):
     """The serving cell's own configuration and mix."""
     c, m = config("dlrm5"), mix("overload_dlrm5")
-    got = control.serve_readings(c, m, seed, 192 / m["rate_qps"])
+    got = control.serve_readings(manifest.model(c), c, m, seed,
+                                 192 / m["rate_qps"])
     assert got["unanswered"] == 0
     assert got["ctr_max_abs_err"] > c["limits"]["ctr_max_abs_err"]
 
 
 def test_training_control_and_fault_fail_a_number():
     c, m = config("dlrm5"), dict(mix("closed_b256"), batch=64)
-    got = control.train_readings(c, m, 2 ** 33 + 3)
+    got = control.train_readings(manifest.model(c), c, m, 2 ** 33 + 3)
     for kind in ("control", "half_batch"):
         assert any(v > c["limits"][k] for k, v in got[kind].items()
                    if k in c["limits"]), kind

@@ -154,8 +154,7 @@ def test_idle_gaps_are_named_by_the_innermost_span_covering_them():
     s = xplane.Summary(starts=np.array([0.0, 3.2, 9.45]),
                        ends=np.array([0.25, 4.05, 10.2]),
                        names=["%a", "%b", "%c"], scopes=["", "", ""])
-    assert pt.idle_gaps(s, 12.0) == [(0.25, 3.2), (4.05, 9.45),
-                                     (10.2, 12.0)]
+    assert s.gaps(12.0) == [(0.25, 3.2), (4.05, 9.45), (10.2, 12.0)]
     got = pt.idle_by_span(s, serve_turn(), 5, 12.0)
     assert [p for p, _ in got] == ["pump/settle/settle_wait",
                                    "pump/dispatch", ""]
@@ -251,7 +250,7 @@ def test_trace_report_runs_a_cell_with_the_program_spans_on(
         checkout, cell, tmp_path, capsys, monkeypatch):
     """On the CPU there is no device plane: the device side is a made-up
     summary, the host side the real trace of the window."""
-    monkeypatch.setattr(xplane, "load", lambda path, hlo: made_up())
+    monkeypatch.setattr(xplane, "load", lambda path, hlo, chips: made_up())
     rc = trace_report.main(["--workload", cell, "--seed", str(2 ** 33 + 5),
                             "--seconds", "0.5", "--keep",
                             str(tmp_path / "kept")],

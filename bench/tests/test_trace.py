@@ -6,11 +6,13 @@ the harness's ``dispatch`` and ``settle`` annotations, and the compiled HLO
 text of that serve step.
 """
 import gzip
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from bench import readers, xplane
+from bench.record import Run
 from bench.tests.helpers import BENCH
 
 DATA = BENCH / "testdata"
@@ -98,3 +100,64 @@ def test_made_up_intervals():
     assert s.idle_gaps(5, 10.0) == [["settle", 3.0], ["dispatch", 1.0],
                                     ["settle", 0.5]]
     assert s.top_ops(2) == [["%while", 4.0], ["%a", 1.5]]
+
+
+def _plane(name, lines):
+    return SimpleNamespace(name=name, lines=[
+        SimpleNamespace(name=ln, events=[
+            SimpleNamespace(name=n, start_ns=int(a * 1e9),
+                            duration_ns=int((b - a) * 1e9))
+            for a, b, n in evs]) for ln, evs in lines.items()])
+
+
+def two_chips():
+    # the window opens at 1 s; chip 0 runs the gather [0, 2) and the head
+    # [3, 4), chip 1 the gather [0, 1) and the head [3, 3.5)
+    hlo = ("%g = f32[8]{0} fusion(), metadata={op_name=\"jit(f)/"
+           "sparse_lookup/emb_lookup/x\"}\n%h = f32[8]{0} fusion(), "
+           "metadata={op_name=\"jit(f)/mlp/y\"}")
+    g, h = "%g = f32[8]{0} fusion()", "%h = f32[8]{0} fusion()"
+    planes = [
+        _plane("/host:CPU", {"python": [(1.0, 6.0, "window"),
+                                        (1.0, 3.0, "dispatch")]}),
+        _plane("/device:TPU:1", {"XLA Ops": [(1.0, 2.0, g),
+                                             (4.0, 4.5, h)]}),
+        _plane("/device:TPU:0", {"XLA Ops": [(1.0, 3.0, g), (4.0, 5.0, h)],
+                                 "Steps": [(1.0, 5.0, "step")]}),
+        _plane("/device:TPU:2", {"XLA Ops": [(1.0, 5.0, g)]}),
+    ]
+    return planes, [hlo]
+
+
+def test_a_trace_of_two_chips_reads_each_chip_alike():
+    planes, hlo = two_chips()
+    s = xplane.summarize(planes, hlo, chips=2)
+    assert s.chips == 2 and s.mapped_share == pytest.approx(1.0)
+    # busy: the mean of 3 s and 1.5 s; a scope: summed over the chips
+    assert s.busy_s(0.0, 5.0) == pytest.approx(2.25)
+    assert s.scope_s(readers.GATHER, 0.0, 5.0) == pytest.approx(3.0)
+    assert s.scope_s(readers.HEAD, 0.0, 5.0) == pytest.approx(1.5)
+    assert s.outside_s(readers.STAGES, 0.0, 5.0) == pytest.approx(0.0)
+    run = Run(config={}, traffic={}, peaks={"bf16_flops_per_s": 1e12,
+                                             "hbm_bytes_per_s": 1e9},
+              window_s=5.0, trace=s)
+    # idle: the mean of 2/5 and 3.5/5
+    assert readers.device_idle_pct(run) == pytest.approx(55.0)
+    # 1.5e9 bytes at the two chips' 2e9 B/s over the mean chip's 1.5 s
+    run.add_work({"gather": (0, 1.5e9)})
+    assert readers.gather_roofline_pct(run) == pytest.approx(50.0)
+    assert readers.step_mfu_pct(run) == pytest.approx(
+        100 * 0.75 / 2.25)
+    # each chip's own gaps: [2, 3) and [4, 5) on chip 0, [1, 3) and
+    # [3.5, 5) on chip 1
+    assert sorted(g for _, g in s.idle_gaps(10, 5.0)) == pytest.approx(
+        [1.0, 1.0, 1.5, 2.0])
+
+
+def test_one_chip_keeps_the_first_plane_and_a_missing_one_is_an_error():
+    planes, hlo = two_chips()
+    s = xplane.summarize(planes, hlo)
+    assert s.busy_s(0.0, 5.0) == pytest.approx(3.0)
+    assert set(s.chip) == {0}
+    with pytest.raises(ValueError, match="/device:TPU:3"):
+        xplane.summarize(planes, hlo, chips=4)

@@ -46,15 +46,18 @@ def traced_window(cell, seed: int, seconds: float, prof: str):
     from repro import obs
 
     from bench import readers
+    from bench.record import chip_mesh
+    mesh = chip_mesh(cell.chips)
     if cell.traffic["kind"] == "open_loop":
         from bench.serve import Serve
-        srv = Serve(cell.config, cell.traffic, seed, traced=True)
+        srv = Serve(cell.model, cell.config, cell.traffic, seed, traced=True,
+                    mesh=mesh)
         run, bodies, _ = srv.window(cell.traffic["rate_qps"], seconds,
                                     seed, prof)
         return (run, srv.engine.telemetry.tracer, srv.hlo_texts(bodies),
                 {"serve_qps": readers.completed_qps(run)})
     from bench.train import Train
-    tr = Train(cell.config, cell.traffic, seed)
+    tr = Train(cell.model, cell.config, cell.traffic, seed, mesh)
     tel = tr.trainer.telemetry
     tel.tracer = obs.Tracer(max_spans=1 << 20, registry=tel.registry)
     tr.first_steps()
@@ -157,7 +160,7 @@ def main(argv=None, *, require_chip: bool = True, root: Path = ROOT) -> int:
                                                args.seconds, prof)
         run.peaks = peaks
         (pb,) = glob.glob(f"{prof}/**/*.xplane.pb", recursive=True)
-        run.trace = xplane.load(pb, hlo)
+        run.trace = xplane.load(pb, hlo, cell.chips)
         spans = program_trace.host_spans(pb)
         if args.keep:
             keep(args.keep, pb, hlo)

@@ -12,42 +12,27 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from bench import loadgen, reference, weights, work
+from bench import loadgen
 from bench.record import (Run, annotate, clock, compile_counter,
                           start_trace)
-from bench.serve import dlrm_config
 
 CHECKED_STEPS = 3
 
 
-def arena_grad_norm(c: dict, state) -> float:
-    """||g|| of the arena from row-wise Adagrad's accumulator after one
-    step, which holds mean(g_row^2) for every row."""
-    acc = state["arena"]["acc"]
-    return float(jnp.sqrt(c["emb_dim"] * jnp.sum(acc.astype(jnp.float32))))
-
-
-def mlp_grad_norms(c: dict, state) -> list:
-    """||g|| per MLP leaf from AdamW's first moment after one step,
-    m = (1 - b1) g."""
-    m = state["mlp"]["m"]
-    b1 = c["optimizer"]["mlp"]["b1"]
-    tree = {"bottom": m["bottom"], "top": m["top"]}
-    return [float(jnp.sqrt(jnp.sum(jnp.square(x)))) / (1.0 - b1)
-            for x in reference.leaves(tree)[1:]]
-
-
-def batch_bodies(c: dict, mix: dict, seed: int, n: int) -> list:
+def batch_bodies(model, c: dict, mix: dict, seed: int, n: int) -> list:
     """The bodies of the first ``n`` distinct batches a run of ``seed``
     trains on, in order."""
-    return [loadgen.bodies(c, mix, mix["batch"], seed * 1000 + k)
+    return [model.bodies(c, mix, mix["batch"], seed * 1000 + k)
             for k in range(n)]
 
 
 class Train:
-    def __init__(self, c: dict, mix: dict, seed: int):
+    """The trainer of one cell; ``model`` is the configuration's model
+    module, and on a ``mesh`` the arena and its optimizer state are
+    row-sharded over it."""
+
+    def __init__(self, model, c: dict, mix: dict, seed: int, mesh=None):
         from repro.training import OnlineTrainer
 
         opt = c["optimizer"]
@@ -55,21 +40,18 @@ class Train:
             raise ValueError("the program's trainer steps the arena at ten "
                              "times the MLP rate; the configuration says "
                              "otherwise")
-        self.c, self.mix, self.seed = c, mix, seed
-        self.bodies = batch_bodies(c, mix, seed, mix["distinct_batches"])
-        self.batches = [loadgen.train_batch(c, mix, b) for b in self.bodies]
-        rows = c["rows_per_table"]
-        self.counts = []
-        for b in self.bodies:
-            table = np.repeat(np.arange(b.lens.size) % c["n_tables"],
-                              b.lens.reshape(-1))
-            flat = b.ids.astype(np.int64) + table * rows
-            self.counts.append((b.ids.size, np.unique(flat).size))
-        params = weights.make(c, seed)
+        self.model, self.c, self.mix, self.seed = model, c, mix, seed
+        self.max_l = loadgen.max_len(mix["bag"])
+        self.bodies = batch_bodies(model, c, mix, seed,
+                                   mix["distinct_batches"])
+        self.batches = [model.train_batch(c, mix, b) for b in self.bodies]
+        self.work = [model.train_work(c, b, mix["batch"])
+                     for b in self.bodies]
+        params = model.weights(c, seed, mesh)
         jax.block_until_ready(params)
-        self.trainer = OnlineTrainer(dlrm_config(c), params,
-                                     max_l=mix["bag"]["max"],
-                                     lr=opt["mlp"]["lr"], sparse=True)
+        self.trainer = OnlineTrainer(model.program_config(c), params,
+                                     max_l=self.max_l, lr=opt["mlp"]["lr"],
+                                     sparse=True, mesh=mesh)
         del params
         self.k = 0
         self.reading_s = 0.0     # time spent reading for the check
@@ -84,24 +66,15 @@ class Train:
         tr = self.trainer
         self.step()
         t = clock()
-        grad_norms = ([arena_grad_norm(self.c, tr.opt_state)]
-                      + mlp_grad_norms(self.c, tr.opt_state))
-        first = reference.train_batch_of(self.bodies[0],
-                                          self.mix["bag"]["max"])
-        row_grads = reference.row_norms(
-            tr.opt_state["arena"]["acc"],
-            reference.touched_rows(first, self.c["n_tables"],
-                                   self.c["rows_per_table"]),
-            self.c["emb_dim"])
+        out = self.model.first_step_readings(self.c, tr, self.bodies[0],
+                                             self.max_l)
         self.reading_s = clock() - t
         for _ in range(CHECKED_STEPS - 1):
             self.step()
         t = clock()
-        change = reference.change_norms(self.c, self.seed, tr.params)
+        out.update(self.model.change_readings(self.c, self.seed, tr))
         self.reading_s += clock() - t
-        return {"losses": list(tr.losses[:CHECKED_STEPS]),
-                "grad_norms": grad_norms, "row_grad_norms": row_grads,
-                "change_norms": change}
+        return {"losses": list(tr.losses[:CHECKED_STEPS]), **out}
 
     def hlo_texts(self) -> list:
         """The compiled train step, as HLO text."""
@@ -110,9 +83,9 @@ class Train:
         return [tr._step.lower(tr.params, tr.opt_state, batch)
                 .compile().as_text()]
 
-    def reference_batches(self) -> list:
-        return [reference.train_batch_of(b, self.mix["bag"]["max"])
-                for b in self.bodies[:CHECKED_STEPS]]
+    def checked_bodies(self) -> list:
+        """The bodies of the checked steps, for the reference."""
+        return self.bodies[:CHECKED_STEPS]
 
     def window(self, seconds: float, profile_dir: str = None) -> Run:
         """Whole steps until ``seconds`` have passed; the rate is taken
@@ -132,9 +105,8 @@ class Train:
         run = Run(config=self.c, traffic=self.mix, peaks={},
                   window_s=steps[-1][1], window_compiles=compiles[0],
                   steps=steps)
-        for _, _, b, k in steps:
-            n_ids, n_unique = self.counts[k]
-            run.add_work(work.train_step(self.c, n_ids, n_unique, b))
+        for _, _, _, k in steps:
+            run.add_work(self.work[k])
         return run
 
     def close(self):

@@ -13,6 +13,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 ARENA_SCALE = 0.01      # row values ~ N(0, 0.01^2), as the program's own init
 BIAS_SCALE = 0.1        # non-zero biases, so a dropped bias shows
@@ -66,9 +67,78 @@ def _shape(c):
             c["dense_features"], tuple(c["bottom_mlp"]), tuple(c["top_mlp"]))
 
 
-def make(c: dict, seed: int) -> dict:
-    """All weights of configuration ``c`` (its JSON dict) from ``seed``."""
-    return _params(seed_key(seed), _shape(c))
+def make(c: dict, seed: int, mesh=None) -> dict:
+    """All weights of configuration ``c`` (its JSON dict) from ``seed``.
+    On a ``mesh``, as the program's sharded paths hold them: the arena
+    padded with zero rows to a multiple of the ``model`` axis and
+    row-sharded over it, the MLPs replicated. Each chip then draws only
+    its own rows, equal bit for bit to theirs in the arena made whole."""
+    if mesh is None:
+        return _params(seed_key(seed), _shape(c))
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rep = NamedSharding(mesh, P())
+    key = jax.device_put(seed_key(seed), rep)
+    return {"arena": _sharded_arena(key, _shape(c), mesh),
+            **jax.device_put(_mlp_params(key, _shape(c)), rep)}
+
+
+def _normal_bits(bits):
+    """``jax.random.normal``'s float32 from its 32 random bits."""
+    lo = np.nextafter(np.float32(-1.0), np.float32(0.0))
+    u = jax.lax.bitcast_convert_type(
+        (bits >> 9) | np.uint32(0x3F800000), jnp.float32) - 1.0
+    u = jnp.maximum(lo, u * (np.float32(1.0) - lo) + lo)
+    return np.float32(np.sqrt(2)) * jax.lax.erf_inv(u)
+
+
+def _arena_rows(k_arena, g, t: int, rows: int, dim: int):
+    """Rows ``g`` of the arena (global row ids; those past the tables are
+    zero). ``jax.random.normal`` draws element ``n`` of a table from the
+    threefry hash of the counter ``n`` under the table's key, so a row can
+    be drawn alone (JAX's partitionable threefry, its default)."""
+    from jax.extend.random import threefry2x32_p
+    if (not jax.config.jax_threefry_partitionable or rows * dim >= 2 ** 32
+            or t * rows >= 2 ** 31):
+        raise ValueError("arena rows are drawn alone only under the "
+                         "partitionable threefry, for tables under 2**32 "
+                         "elements and arenas under 2**31 rows")
+    keys = jax.vmap(lambda i: jax.random.fold_in(k_arena, i))(
+        jnp.arange(t))[jnp.minimum(g // rows, t - 1)]
+    n = ((g % rows).astype(jnp.uint32)[:, None] * np.uint32(dim)
+         + jnp.arange(dim, dtype=jnp.uint32))
+    b1, b2 = threefry2x32_p.bind(keys[:, :1], keys[:, 1:],
+                                 jnp.zeros_like(n), n)
+    x = ARENA_SCALE * _normal_bits(b1 ^ b2)
+    return jnp.where((g < t * rows)[:, None], x, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "mesh", "block"))
+def _sharded_arena(key, shape, mesh, block=2 ** 24):
+    """The arena row-sharded over ``mesh``, each chip drawing its rows in
+    blocks of ``block`` elements, so that no chip holds more than its
+    share and one block's scratch."""
+    from jax.sharding import PartitionSpec as P
+    t, rows, dim = shape[:3]
+    local = -(-(t * rows + 1) // mesh.shape["model"])
+    block = min(local, max(1, block // dim))
+
+    def body(key):
+        k_arena = _keys(key)[0]
+        lo = jax.lax.axis_index("model") * local
+
+        def fill(k, out):
+            # the last block ends at the shard's end, drawing again a few
+            # rows of the one before it
+            start = jnp.minimum(k * block, local - block)
+            g = lo + start + jnp.arange(block)
+            return jax.lax.dynamic_update_slice(
+                out, _arena_rows(k_arena, g, t, rows, dim), (start, 0))
+
+        return jax.lax.fori_loop(0, -(-local // block), fill,
+                                 jnp.zeros((local, dim), jnp.float32))
+
+    return jax.shard_map(body, mesh=mesh, in_specs=P(),
+                         out_specs=P("model", None), check_vma=False)(key)
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
